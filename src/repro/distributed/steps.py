@@ -37,32 +37,7 @@ from .sharding import (
     params_pspecs,
     params_shardings,
 )
-from .sync import (
-    STRATEGIES,
-    _LEGACY_SHARD_MAP,  # single source of truth for the legacy-jax shims
-    all_gather_compat,
-    sync_allreduce,
-    sync_hier,
-    sync_hier_int8,
-)
-
-if not _LEGACY_SHARD_MAP:  # jax >= 0.6: shard_map in the top-level namespace
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=True):
-        """Adapt the new keyword surface onto jax.experimental.shard_map.
-
-        ``axis_names`` lists the *manual* axes; the old API instead takes
-        ``auto`` = the complement.  ``check_vma`` was called ``check_rep``.
-        """
-        manual = frozenset(mesh.axis_names if axis_names is None else axis_names)
-        auto = frozenset(mesh.axis_names) - manual
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma, auto=auto,
-        )
+from .sync import STRATEGIES, sync_allreduce, sync_hier, sync_hier_int8
 
 
 class TrainState(NamedTuple):
@@ -123,11 +98,7 @@ def make_train_step(
     diloco_cfg = diloco_cfg or DilocoConfig()
     multi_pod = "pod" in mesh.axis_names
 
-    def inner(params, state: TrainState, batch, pod_idx=None):
-        # ``pod_idx`` is a length-1 slice of arange(npods) sharded over the
-        # manual "pod" axis — position info without jax.lax.axis_index,
-        # whose PartitionId lowering old partitioners reject here.
-        idx = pod_idx[0] if pod_idx is not None else None
+    def inner(params, state: TrainState, batch):
         # batch enters sharded over "pod" only (manual); constrain the
         # embedding output onto "data" so GSPMD spreads activations without
         # partitioning the token-gather indices (XLA CPU partitioner bug —
@@ -136,12 +107,6 @@ def make_train_step(
             "data" if "data" in mesh.axis_names else None
         )
         seq_axes = "model" if "model" in mesh.axis_names else None
-        if multi_pod and _LEGACY_SHARD_MAP:
-            # pre-0.6 SPMD partitioners CHECK-fail on sharding constraints
-            # naming auto axes inside a partial-manual region; the
-            # constraints are perf hints, so dropping them is numerically
-            # identical (activations stay GSPMD-propagated).
-            act_axes = seq_axes = None
         with activation_sharding(act_axes, seq_axes):
             (loss, metrics), grads = jax.value_and_grad(
                 lambda p: loss_fn(p, batch, cfg), has_aux=True
@@ -156,7 +121,7 @@ def make_train_step(
             elif strategy == "hier":
                 grads = sync_hier(grads, num_channels=num_channels)
             elif strategy == "hier_int8":
-                grads, new_ef = sync_hier_int8(grads, state.ef, axis_index=idx)
+                grads, new_ef = sync_hier_int8(grads, state.ef)
             elif strategy in ("ps", "local_sgd"):
                 pass  # ps: handled after the optimizer; local_sgd: no WAN here
 
@@ -170,17 +135,13 @@ def make_train_step(
             # everyone receives its parameters (full WAN broadcast).  The
             # push phase is the all_gather of gradients below.
             gathered = jax.tree.map(
-                lambda g: all_gather_compat(
-                    g.astype(jnp.float32), "pod", axis_index=idx
-                ),
-                grads,
+                lambda g: jax.lax.all_gather(g.astype(jnp.float32), "pod"), grads
             )
             g_mean = jax.tree.map(lambda g: jnp.mean(g, axis=0), gathered)
             new_params, new_adam, opt_metrics = adamw_update(
                 opt_cfg, g_mean, state.adam, params
             )
-            server_idx = jax.lax.axis_index("pod") if idx is None else idx
-            is_server = (server_idx == 0).astype(jnp.float32)
+            is_server = (jax.lax.axis_index("pod") == 0).astype(jnp.float32)
             new_params = jax.tree.map(
                 lambda u: jax.lax.psum(u * is_server.astype(u.dtype), "pod"), new_params
             )
@@ -233,14 +194,13 @@ def make_train_step(
             jax.tree.map(lambda s: P(), p_pspec, is_leaf=lambda x: isinstance(x, P)),
             jax.tree.map(lambda s: P(), s_pspec, is_leaf=lambda x: isinstance(x, P)),
             jax.tree.map(pod_batch_spec, b_pspec, is_leaf=lambda x: isinstance(x, P)),
-            P("pod"),
         )
         out_specs = (
             jax.tree.map(lambda s: P(), p_pspec, is_leaf=lambda x: isinstance(x, P)),
             jax.tree.map(lambda s: P(), s_pspec, is_leaf=lambda x: isinstance(x, P)),
             P(),
         )
-        sharded = _shard_map(
+        fn = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=in_specs,
@@ -248,10 +208,6 @@ def make_train_step(
             axis_names={"pod"},
             check_vma=False,
         )
-        npods = int(mesh.shape["pod"])
-
-        def fn(params, state, batch):
-            return sharded(params, state, batch, jnp.arange(npods, dtype=jnp.int32))
     else:
         fn = inner
 

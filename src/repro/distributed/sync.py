@@ -42,31 +42,6 @@ from .compression import (
 
 STRATEGIES = ("allreduce", "ps", "hier", "hier_int8", "local_sgd")
 
-#: pre-0.6 jax: the old SPMD partitioner CHECK-fails on all-gather of
-#: auto-axis-sharded operands beneath a manual "pod" sub-mesh.
-_LEGACY_SHARD_MAP = not hasattr(jax, "shard_map")
-
-
-def all_gather_compat(x, axis: str, *, axis_index=None):
-    """``jax.lax.all_gather`` with a legacy-safe lowering.
-
-    On old jax the gather is expressed as a one-hot psum (exact for the
-    int8/f32 payloads used on the WAN hop: int8 values round-trip through
-    f32 losslessly), which the old partitioner handles fine.
-    ``axis_index`` lets callers under a partial-manual mesh supply the
-    position explicitly (``jax.lax.axis_index`` lowers to a PartitionId
-    instruction the old partitioner rejects there).
-    """
-    if not _LEGACY_SHARD_MAP:
-        return jax.lax.all_gather(x, axis)
-    n = jax.lax.psum(1, axis)  # folds to the static axis size
-    idx = jax.lax.axis_index(axis) if axis_index is None else axis_index
-    mask = jax.lax.broadcasted_iota(jnp.int32, (n,) + (1,) * x.ndim, 0) == idx
-    xf = x.astype(jnp.float32)
-    out = jax.lax.psum(jnp.where(mask, xf[None], 0.0), axis)
-    return out.astype(x.dtype)
-
-
 def _chunk_bounds(dim0: int, num_channels: int):
     """Static slice bounds splitting dim 0 into <= num_channels parts."""
     base, rem = divmod(dim0, num_channels)
@@ -122,7 +97,7 @@ def sync_hier(grads, *, axis: str = "pod", num_channels: int = 4):
     return jax.tree.map(one, _f32(grads))
 
 
-def sync_hier_int8(grads, ef, *, axis: str = "pod", num_channels: int = 4, axis_index=None):
+def sync_hier_int8(grads, ef, *, axis: str = "pod"):
     """int8 + error feedback on the WAN hop.
 
     Pattern: g' = g + ef; q = quant(g'); all-gather(q) over pod; dequant &
@@ -135,8 +110,8 @@ def sync_hier_int8(grads, ef, *, axis: str = "pod", num_channels: int = 4, axis_
 
     def one(g):
         c = int8_compress(g)
-        vals = all_gather_compat(c.values, axis, axis_index=axis_index)  # (npods, ..., L) int8
-        scls = all_gather_compat(c.scales, axis, axis_index=axis_index)  # (npods, ..., L/B) f32
+        vals = jax.lax.all_gather(c.values, axis)  # (npods, ..., L) int8
+        scls = jax.lax.all_gather(c.scales, axis)  # (npods, ..., L/B) f32
         nblocks = c.scales.shape[-1]
         blocks = vals.reshape(*vals.shape[:-1], nblocks, -1).astype(jnp.float32)
         deq = (blocks * scls[..., None]).reshape(vals.shape).sum(0)
@@ -156,7 +131,7 @@ def sync_hier_int8(grads, ef, *, axis: str = "pod", num_channels: int = 4, axis_
     return synced, new_ef
 
 
-def sync_ps(grads, params, apply_update: Callable, *, axis: str = "pod", axis_index=None):
+def sync_ps(grads, params, apply_update: Callable, *, axis: str = "pod"):
     """Parameter-server emulation (paper M1).
 
     Workers push gradients to the server (pod 0), the server applies the
@@ -170,15 +145,12 @@ def sync_ps(grads, params, apply_update: Callable, *, axis: str = "pod", axis_in
     values (identical computation everywhere; non-0 pods discard).
     Returns the broadcast updated params.
     """
-    idx = jax.lax.axis_index(axis) if axis_index is None else axis_index
     # push: server receives every pod's gradients
-    gathered = jax.tree.map(
-        lambda g: all_gather_compat(g, axis, axis_index=idx), grads
-    )
+    gathered = jax.tree.map(lambda g: jax.lax.all_gather(g, axis), grads)
     g_mean = jax.tree.map(lambda g: g.mean(0), gathered)
     updated = apply_update(g_mean)
     # pull: only the server's copy survives the broadcast
-    is_server = (idx == 0).astype(jnp.float32)
+    is_server = (jax.lax.axis_index(axis) == 0).astype(jnp.float32)
 
     def bcast(u):
         return jax.lax.psum(u * is_server.astype(u.dtype), axis)

@@ -1,4 +1,8 @@
-"""Jit-ready wrapper for the WKV6 Pallas kernel."""
+"""Jit-ready wrapper for the WKV6 Pallas kernel.
+
+Shapes the kernel cannot tile raise; callers that want the jnp reference
+call :func:`repro.kernels.rwkv6_wkv.ref.wkv6_ref` by name.
+"""
 
 from __future__ import annotations
 
@@ -9,23 +13,19 @@ import jax
 import jax.numpy as jnp
 
 from .kernel import wkv6_fwd
-from .ref import wkv6_ref
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6(
     r, k, v, w, u, state0=None, *,
     chunk: int = 128,
-    interpret: bool = True,
-    use_kernel: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """RWKV6 recurrence. r/k/v/w: [B, T, H, N]; u: [H, N].
 
     Returns (out [B, T, H, N] f32, final state [B, H, N, N] f32).
     """
-    b, t, h, n = r.shape
+    b, _, h, n = r.shape
     if state0 is None:
         state0 = jnp.zeros((b, h, n, n), jnp.float32)
-    if not use_kernel or t % min(chunk, t) != 0:
-        return wkv6_ref(r, k, v, w, u, state0)
     return wkv6_fwd(r, k, v, w, u, state0, chunk=chunk, interpret=interpret)

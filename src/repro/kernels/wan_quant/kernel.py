@@ -3,16 +3,21 @@
 The compute hot-spot introduced by the paper's setting: gradients must be
 compressed *at line rate* before the inter-data-center hop (hier_int8
 sync), i.e. the quantizer must stream the full gradient through the VPU
-faster than the WAN drains it.  The kernel tiles [rows, lanes] into
-(row_tile x 256-lane) VMEM blocks — 256 lanes is both the wire-format
-block (one f32 scale per 256 int8 payload) and a multiple of the VPU lane
-width, so absmax reduction and scaling vectorize with no cross-lane
-shuffles.  Quantize and dequantize are separate kernels (they run on
-opposite sides of the WAN).
+faster than the WAN drains it.  The grid walks ``row_tile``-row slabs of
+a [rows, lanes] array; each grid step holds whole rows, so its scale
+block ``(row_tile, lanes/256)`` spans the full scale width — the only
+legal TPU block for a scale array narrower than 128 lanes.  Inside a
+slab the 256-lane wire-format blocks (one f32 scale per 256 int8
+payload) are static, 128-aligned lane slices, so absmax reduction and
+scaling vectorize with no cross-lane shuffles.  Row tiles are multiples
+of 32 (the int8 sublane tiling) unless one tile covers every row.
+Quantize and dequantize are separate kernels (they run on opposite sides
+of the WAN).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -20,25 +25,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams; accept either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 BLOCK = 256  # lanes per scale block (wire format)
 ROW_TILE = 256  # rows per grid step
+ROW_ALIGN = 32  # int8 sublane tiling: a partial row tile is a multiple of this
 
 
-def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)  # [rt, BLOCK]
-    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)  # [rt, 1]
-    scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(x / scale), -127, 127)
-    q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale
+def _checked_row_tile(rows: int, row_tile: int) -> int:
+    """The row tile the kernels use for ``rows``; raises if the TPU cannot tile it."""
+    rt = min(row_tile, rows)
+    if rows % rt or (rt != rows and rt % ROW_ALIGN):
+        raise ValueError(
+            f"row tile {rt} cannot tile {rows} rows: it must divide them and be "
+            f"a multiple of {ROW_ALIGN} (pad rows with repro.kernels.wan_quant.ops)"
+        )
+    return rt
 
 
-def _dequant_kernel(q_ref, s_ref, x_ref):
-    q = q_ref[...].astype(jnp.float32)
-    x_ref[...] = q * s_ref[...]
+def _quant_kernel(x_ref, q_ref, s_ref, *, nblocks: int):
+    col = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
+    scales = jnp.zeros(s_ref.shape, jnp.float32)
+    for c in range(nblocks):
+        lanes = slice(c * BLOCK, (c + 1) * BLOCK)
+        x = x_ref[:, lanes].astype(jnp.float32)  # [rt, BLOCK]
+        absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)  # [rt, 1]
+        scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
+        q_ref[:, lanes] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
+        scales = jnp.where(col == c, scale, scales)
+    s_ref[...] = scales
+
+
+def _dequant_kernel(q_ref, s_ref, x_ref, *, nblocks: int):
+    s = s_ref[...]  # [rt, nblocks]
+    for c in range(nblocks):
+        lanes = slice(c * BLOCK, (c + 1) * BLOCK)
+        x_ref[:, lanes] = q_ref[:, lanes].astype(jnp.float32) * s[:, c : c + 1]
 
 
 def wan_quant(
@@ -46,26 +66,25 @@ def wan_quant(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: [rows, lanes] -> (int8 [rows, lanes], scales f32 [rows, lanes/256])."""
     rows, lanes = x.shape
-    assert lanes % BLOCK == 0, lanes
-    rt = min(row_tile, rows)
-    assert rows % rt == 0, (rows, rt)
+    if lanes % BLOCK:
+        raise ValueError(f"lanes {lanes} is not a multiple of {BLOCK}")
+    rt = _checked_row_tile(rows, row_tile)
     nblocks = lanes // BLOCK
     q, s = pl.pallas_call(
-        _quant_kernel,
-        grid=(rows // rt, nblocks),
-        in_specs=[pl.BlockSpec((rt, BLOCK), lambda r, c: (r, c))],
+        functools.partial(_quant_kernel, nblocks=nblocks),
+        grid=(rows // rt,),
+        in_specs=[pl.BlockSpec((rt, lanes), lambda r: (r, 0))],
         out_specs=[
-            pl.BlockSpec((rt, BLOCK), lambda r, c: (r, c)),
-            pl.BlockSpec((rt, 1), lambda r, c: (r, c)),
+            pl.BlockSpec((rt, lanes), lambda r: (r, 0)),
+            pl.BlockSpec((rt, nblocks), lambda r: (r, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
             jax.ShapeDtypeStruct((rows, nblocks), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="wan_quant",
     )(x)
     return q, s
 
@@ -74,21 +93,22 @@ def wan_dequant(
     q: jnp.ndarray, scales: jnp.ndarray, *, row_tile: int = ROW_TILE,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """(int8 [rows, lanes], scales f32 [rows, lanes/256]) -> f32 [rows, lanes]."""
     rows, lanes = q.shape
-    rt = min(row_tile, rows)
-    assert rows % rt == 0 and lanes % BLOCK == 0
+    if lanes % BLOCK:
+        raise ValueError(f"lanes {lanes} is not a multiple of {BLOCK}")
+    rt = _checked_row_tile(rows, row_tile)
     nblocks = lanes // BLOCK
     return pl.pallas_call(
-        _dequant_kernel,
-        grid=(rows // rt, nblocks),
+        functools.partial(_dequant_kernel, nblocks=nblocks),
+        grid=(rows // rt,),
         in_specs=[
-            pl.BlockSpec((rt, BLOCK), lambda r, c: (r, c)),
-            pl.BlockSpec((rt, 1), lambda r, c: (r, c)),
+            pl.BlockSpec((rt, lanes), lambda r: (r, 0)),
+            pl.BlockSpec((rt, nblocks), lambda r: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((rt, BLOCK), lambda r, c: (r, c)),
+        out_specs=pl.BlockSpec((rt, lanes), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="wan_dequant",
     )(q, scales)

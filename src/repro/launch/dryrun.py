@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture x input-shape) cell, on BOTH production meshes
@@ -21,6 +18,11 @@ collective-bytes — necessary because ``cost_analysis`` counts a
 Artifacts land in artifacts/dryrun/<arch>__<shape>__<mesh>.json; the
 roofline benchmark and EXPERIMENTS.md read from there.
 
+The meshes are 512 fake CPU devices: ``main`` pins JAX to the CPU and
+sets ``XLA_FLAGS`` before the first device query, so importing this
+module changes nothing, and the per-cell worker processes never reach
+for an accelerator the parent may hold.
+
 Usage:
     python -m repro.launch.dryrun --arch yi-34b --shape train_4k --mesh single
     python -m repro.launch.dryrun --all --mesh both [--skip-existing]
@@ -29,6 +31,9 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
+import subprocess
+import sys
 import time
 import traceback
 from pathlib import Path
@@ -57,6 +62,10 @@ from repro.models import decode_step, prefill
 from repro.optim import AdamWConfig
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
+
+#: environment of the dry-run process and its per-cell workers: CPU only,
+#: with enough fake devices for the 2x16x16 production mesh
+CPU_ENV = {"JAX_PLATFORMS": "cpu", "XLA_FLAGS": "--xla_force_host_platform_device_count=512"}
 
 # v5e-class chip constants (roofline; see EXPERIMENTS.md §Roofline)
 PEAK_FLOPS_BF16 = 197e12
@@ -353,6 +362,8 @@ def _print_cell(rec: dict, wall: float) -> None:
 
 
 def main() -> None:
+    os.environ.update(CPU_ENV)  # read when the CPU backend is first created
+    jax.config.update("jax_platforms", "cpu")
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=list(ASSIGNED_ARCHS), default=None)
     ap.add_argument("--shape", choices=list(SHAPES), default=None)
@@ -394,8 +405,6 @@ def main() -> None:
                 else:
                     # isolate each cell: XLA partitioner CHECK failures abort
                     # the process; a subprocess confines the blast radius.
-                    import subprocess, sys
-
                     cmd = [
                         sys.executable, "-m", "repro.launch.dryrun",
                         "--arch", arch, "--shape", shape_name, "--mesh", mesh_name,
@@ -403,7 +412,10 @@ def main() -> None:
                     ]
                     if args.no_probes:
                         cmd.append("--no-probes")
-                    proc = subprocess.run(cmd, capture_output=True, text=True)
+                    proc = subprocess.run(
+                        cmd, capture_output=True, text=True,
+                        env={**os.environ, **CPU_ENV},
+                    )
                     if path.exists():
                         rec = json.loads(path.read_text())
                     else:

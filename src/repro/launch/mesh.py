@@ -11,37 +11,28 @@ state (device count locks on first backend initialization).
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 exposes explicit axis types; older releases have neither
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
-def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """jax.make_mesh across jax versions (axis_types only where supported)."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+def make_mesh(
+    shape: Tuple[int, ...], axes: Tuple[str, ...], *, devices: Optional[Sequence] = None
+) -> Mesh:
+    """Mesh with every axis GSPMD-auto (the train step's ``shard_map`` makes
+    ``pod`` manual) over ``devices`` (default: all of the process's devices).
+    Used by the launchers, tests and the elastic re-mesh."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh (used by tests and the elastic re-mesh path)."""
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(

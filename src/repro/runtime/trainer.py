@@ -18,7 +18,6 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import AsyncCheckpointer, CheckpointStore
@@ -139,6 +138,8 @@ class GeoTrainer:
             (params, state), meta = self.store.restore(latest, (params, state))
             start_step = int(meta.get("data_step", latest))
             self.loader.step = start_step
+        params = jax.device_put(params, self.shardings["params"])
+        state = jax.device_put(state, self.shardings["state"])
         return params, state, start_step
 
     def _ckpt_interval(self, step_time_s: float) -> int:
@@ -200,11 +201,12 @@ class GeoTrainer:
         # steps) regardless of wall-clock step duration.
         interval_ms = next(iter(self.heartbeats.workers.values())).session.interval_ms
         sim_ms = 0.0
+        batch = {}
         with self.mesh:
             for step in range(start, tc.steps):
                 for ev in events_by_step.get(step, ()):
                     apply_event(ev, self.geo, scenario_rollup, straggler_noop)
-                batch = {k: jnp.asarray(v) for k, v in self.loader.next_batch().items()}
+                batch = jax.device_put(self.loader.next_batch(), self.shardings["batch"])
                 t0 = time.time()
                 params, state, metrics = self.step_fn(params, state, batch)
                 loss = float(metrics["loss"])
@@ -257,6 +259,11 @@ class GeoTrainer:
         self.ckpt.wait()
         return {
             "final_loss": self.metrics_log[-1]["loss"] if self.metrics_log else None,
+            "params": params,
+            "state": state,
+            "batch_devices": len(
+                {d for leaf in jax.tree.leaves(batch) for d in leaf.sharding.device_set}
+            ),
             "metrics": self.metrics_log,
             "recovery_drills": recovery_drills,
             "sync_efficiency": self.stragglers.sync_efficiency(),

@@ -34,10 +34,13 @@ everything else.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import multiprocessing
+import os
 from concurrent.futures import ProcessPoolExecutor
 from dataclasses import dataclass
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -231,10 +234,33 @@ def _run_variant_payload(payload: Dict[str, object]) -> Dict[str, float]:
     return run_scenario(Scenario.from_dict(payload)).metrics()
 
 
+@contextlib.contextmanager
+def _cpu_pool(workers: int) -> Iterator[ProcessPoolExecutor]:
+    """A process pool whose workers are spawned with ``JAX_PLATFORMS=cpu``.
+
+    The simulator never needs an accelerator, and a worker must not reach
+    for a chip the parent may hold: workers start from a fresh interpreter
+    (``spawn``, never ``fork``), and the variable is in their environment
+    before their first import.  The parent's environment is restored.
+    """
+    saved = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        with ProcessPoolExecutor(
+            max_workers=workers, mp_context=multiprocessing.get_context("spawn")
+        ) as pool:
+            yield pool
+    finally:
+        if saved is None:
+            os.environ.pop("JAX_PLATFORMS", None)
+        else:
+            os.environ["JAX_PLATFORMS"] = saved
+
+
 def run_sweep(sweep: Sweep, *, workers: int = 0) -> SweepResult:
     """Execute every variant and join the per-variant metrics.
 
-    ``workers > 1`` fans the variants out over a process pool
+    ``workers > 1`` fans the variants out over a CPU-pinned process pool
     (``run_scenario`` is embarrassingly parallel); results are joined in
     variant order and each variant's randomness is seeded by its own spec,
     so the table is identical for any worker count — pinned by
@@ -244,7 +270,7 @@ def run_sweep(sweep: Sweep, *, workers: int = 0) -> SweepResult:
     variants = sweep.variants()
     payloads = [v.to_dict() for v in variants]
     if workers > 1:
-        with ProcessPoolExecutor(max_workers=workers) as pool:
+        with _cpu_pool(workers) as pool:
             metrics = list(pool.map(_run_variant_payload, payloads))
     else:
         metrics = [_run_variant_payload(p) for p in payloads]

@@ -1,5 +1,9 @@
 """Pallas kernel sweeps: shapes x dtypes vs pure-jnp oracles (interpret mode).
 
+The kernels and their wrappers default to the compiled TPU path, so every
+call here passes ``interpret=True``; ``tests/test_tpu_compile.py`` compiles
+the same kernels for a described v5e chip.
+
 Per the assignment: every kernel sweeps shapes/dtypes and asserts allclose
 against its ref.py oracle.
 """
@@ -10,8 +14,10 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention import flash_attention, flash_attention_fwd, flash_attention_ref
-from repro.kernels.rwkv6_wkv import wkv6_fwd, wkv6_ref
+from repro.kernels.rwkv6_wkv import wkv6, wkv6_fwd, wkv6_ref
 from repro.kernels.wan_quant import (
+    dequantize,
+    quantize,
     wan_dequant,
     wan_dequant_ref,
     wan_quant,
@@ -87,27 +93,22 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (b, s, h, hd))
         k = jax.random.normal(ks[1], (b, s, kvh, hd))
         v = jax.random.normal(ks[2], (b, s, kvh, hd))
-        out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+        out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128, interpret=True)
         pos = jnp.arange(s)
         ref = sdpa(q, k, v, q_positions=pos, k_positions=pos, impl="naive")
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
     def test_fallback_tiny_shapes(self):
-        """Non-tileable shapes fall back to the reference implementation."""
+        """Non-tileable shapes raise instead of silently running the reference."""
         q, k, v = _qkv(jax.random.PRNGKey(6), 1, 48, 48, 2, 2, 32, jnp.float32)
-        out = flash_attention(
-            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-            causal=True,
-        )
-        ref = flash_attention_ref(q, k, v, causal=True)
-        np.testing.assert_allclose(
-            np.asarray(jnp.swapaxes(out, 1, 2)), np.asarray(ref), rtol=2e-5, atol=2e-5
-        )
+        swap = lambda x: jnp.swapaxes(x, 1, 2)
+        with pytest.raises(ValueError, match="no 128-multiple block"):
+            flash_attention(swap(q), swap(k), swap(v), causal=True, interpret=True)
 
 
 class TestWanQuant:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("rows,lanes,rt", [(8, 256, 8), (64, 512, 32), (256, 1024, 256), (13, 256, 1)])
+    @pytest.mark.parametrize("rows,lanes,rt", [(8, 256, 8), (64, 512, 32), (256, 1024, 256), (13, 256, 13)])
     def test_sweep_vs_ref(self, dtype, rows, lanes, rt):
         x = (jax.random.normal(jax.random.PRNGKey(rows), (rows, lanes)) * 5).astype(dtype)
         xf = x.astype(jnp.float32)
@@ -146,6 +147,32 @@ class TestWanQuant:
         q_k, s_k = wan_quant(x, row_tile=16, interpret=True)
         np.testing.assert_array_equal(np.asarray(q_k), np.asarray(c.values))
         np.testing.assert_allclose(np.asarray(s_k), np.asarray(c.scales), rtol=1e-6)
+
+
+    @pytest.mark.parametrize("rows,rt", [(13, 1), (64, 16), (96, 64)])
+    def test_illegal_row_tile_raises(self, rows, rt):
+        """A row tile the TPU cannot tile is refused, not silently run."""
+        x = jnp.ones((rows, 256), jnp.float32)
+        with pytest.raises(ValueError, match="row tile"):
+            wan_quant(x, row_tile=rt, interpret=True)
+
+    @pytest.mark.parametrize(
+        "shape", [(50257, 768), (6, 768, 2304), (3, 70), (300,), ()]
+    )
+    def test_ops_pad_rows_and_lanes(self, shape):
+        """quantize/dequantize pad any leaf to legal tiles and strip the padding."""
+        x = jax.random.normal(jax.random.PRNGKey(12), shape) * 2
+        q, s = quantize(x, interpret=True)
+        rows = x.reshape(1, -1) if x.ndim < 2 else x.reshape(-1, x.shape[-1])
+        assert q.shape[0] == s.shape[0] == rows.shape[0]
+        pad = (-rows.shape[-1]) % 256
+        q_r, s_r = wan_quant_ref(jnp.pad(rows, ((0, 0), (0, pad))))
+        assert int(jnp.abs(q.astype(jnp.int32) - q_r.astype(jnp.int32)).max()) <= 1
+        np.testing.assert_allclose(np.asarray(s), np.asarray(s_r), rtol=1e-6)
+        back = dequantize(q, s, orig_shape=tuple(x.shape), interpret=True)
+        assert back.shape == x.shape
+        bound = float(jnp.abs(x).max()) / 254.0 * 1.01 + 1e-7
+        assert float(jnp.abs(back - x).max()) <= bound
 
 
 class TestWkv6:
@@ -192,3 +219,22 @@ class TestWkv6:
         out_m, fin_m = _wkv_with_initial_state(r, k, v, w, u, s0)
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_m), rtol=1e-4, atol=1e-5)
         np.testing.assert_allclose(np.asarray(fin_k), np.asarray(fin_m), rtol=1e-4, atol=1e-5)
+
+    def test_ops_wrapper_default_state(self):
+        """ops.wkv6 starts from a zero state and matches the reference."""
+        b, t, h, n = 1, 32, 2, 16
+        ks = jax.random.split(jax.random.PRNGKey(2), 5)
+        r, k, v = (jax.random.normal(ks[i], (b, t, h, n)) * 0.5 for i in range(3))
+        w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, t, h, n)) + 2.0)
+        u = jax.random.normal(ks[4], (h, n)) * 0.1
+        out_k, fin_k = wkv6(r, k, v, w, u, chunk=16, interpret=True)
+        out_r, fin_r = wkv6_ref(r, k, v, w, u, jnp.zeros((b, h, n, n)))
+        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(fin_k), np.asarray(fin_r), rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("t,chunk", [(24, 12), (30, 30)])
+    def test_untileable_chunk_raises(self, t, chunk):
+        """A chunk that is not a multiple of 8 timesteps is refused."""
+        x = jnp.ones((1, t, 1, 8))
+        with pytest.raises(ValueError, match="cannot tile"):
+            wkv6(x, x, x, x, jnp.ones((1, 8)), chunk=chunk, interpret=True)

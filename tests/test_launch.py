@@ -156,3 +156,38 @@ class TestMeshHelpers:
         single = _FakeMesh((4, 2), ("data", "model"))
         assert num_pods(single) == 1
         assert batch_axes(single) == ("data",)
+
+
+class TestCompileCache:
+    """The persistent compile cache is placed from outside, else at a fixed
+    path in the checkout (never a temporary or per-process one)."""
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_dir_is_fixed_in_checkout(self, monkeypatch):
+        from pathlib import Path
+
+        from repro.launch.compile_cache import enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        saved = jax.config.jax_compilation_cache_dir
+        try:
+            path = enable_compile_cache()
+            assert path == str(Path(__file__).resolve().parents[1] / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", saved)
+
+    def test_compile_stats_count_backend_compiles(self):
+        from repro.launch.compile_cache import CompileStats
+
+        with CompileStats() as stats:
+            jax.jit(lambda x: x * 3 + 1)(jax.numpy.arange(7.0)).block_until_ready()
+        assert stats.compile_s > 0
+        assert "compile" in stats.summary()

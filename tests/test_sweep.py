@@ -276,6 +276,19 @@ class TestSweepEngine:
             r.to_dict() for r in parallel.rows
         ]
 
+    def test_workers_are_spawned_pinned_to_the_cpu(self, monkeypatch):
+        """Sweep workers see JAX_PLATFORMS=cpu from their first import and
+        the parent's environment is left as it was."""
+        import os
+
+        from repro.scenario.sweep import _cpu_pool
+
+        monkeypatch.setenv("JAX_PLATFORMS", "parent-value")
+        with _cpu_pool(1) as pool:
+            assert pool.submit(os.getenv, "JAX_PLATFORMS").result(timeout=120) == "cpu"
+            assert pool._mp_context.get_start_method() == "spawn"
+        assert os.environ["JAX_PLATFORMS"] == "parent-value"
+
     def test_benefit_curve_decays_with_rtt(self):
         curve = overlap_benefit_curve(run_sweep(self._small_sweep()))
         assert len(curve) == 2
