@@ -16,18 +16,21 @@ One chip (no arguments):
   decode step's logits match a full forward pass over the generated
   sequence.
 * **kernels** — the ``wan_quant`` / ``wan_dequant`` Pallas kernels,
-  compiled, on a distilgpt2 MLP leaf, against ``ref.py``.
+  compiled, against ``ref.py``: on a distilgpt2 MLP leaf, and on a
+  vocabulary-wide row (768 x 50257), which the kernels walk in two lane
+  groups, the second overhanging the row.
 
 Four chips (``--four-chips``): distilgpt2-82m at full width on
 ``make_host_mesh(pods=2)`` = (pod=2, data=2, model=1), 3 steps each of
 ``allreduce``, ``hier``, ``hier_int8``, ``ps`` and ``local_sgd`` through
 ``repro.launch.train.main``, against a one-chip run on the same global
-batch: step 0's loss within 1e-3 relative for every strategy; every
-step's loss within 1e-3 relative for ``allreduce``, ``hier`` and ``ps``
-and within 1e-2 for ``hier_int8`` (int8 quantization zeroes gradient
-entries below 1/254 of their block's absmax, and Adam's first updates are
-nearly sign-only); ``local_sgd`` trains each pod on its own half of the
-batch, so only its step 0 is compared.
+batch.  Every strategy's step-0 loss, and every step's loss of the four
+strategies that sync the pods each step, lies within ``LOSS_RTOL``
+relative of one chip.  ``local_sgd`` exchanges nothing between the pods
+before its first outer sync (every 8 steps), so over 3 steps each pod
+trains on its own half of the batch: it is the control, and its losses
+after step 0 must lie *outside* ``LOSS_RTOL``, which shows the bound
+catches a run whose cross-pod exchange is missing.
 
 Timings (compile apart from run), losses and peak device memory go to
 earlier lines; the last line is one JSON object naming the device.  The
@@ -50,10 +53,20 @@ from pathlib import Path
 CHECKOUT = Path(__file__).resolve().parent
 ARCH = "distilgpt2-82m"
 SEED = 0
-STRATEGIES = ("allreduce", "hier", "hier_int8", "ps", "local_sgd")
-#: relative bound on each step's loss against the one-chip run
-LOSS_RTOL = {"allreduce": 1e-3, "hier": 1e-3, "ps": 1e-3, "hier_int8": 1e-2}
-STEP0_RTOL = 1e-3
+SEQ_LEN = 1024
+BATCH = 8
+TRAIN_STEPS = 5
+PROMPT_LEN = 512
+GEN_TOKENS = 64
+#: distilgpt2's stacked MLP up-projection, and its vocabulary-wide unembedding row
+KERNEL_LEAVES = ((6, 768, 3072), (768, 50257))
+FOUR_CHIP_STEPS = 3
+SYNCED = ("allreduce", "hier", "hier_int8", "ps")
+CONTROL = "local_sgd"
+#: relative bound on each step's loss against the one-chip run.  On a TPU v5e
+#: the synced strategies read at most 3.05e-6 and the unsynced control
+#: 1.13e-4 at step 1, so the bound sits between the two.
+LOSS_RTOL = 3e-5
 
 
 class SmokeFailure(RuntimeError):
@@ -100,7 +113,7 @@ def memory_report(devs) -> str:
 # -- one chip ------------------------------------------------------------------
 
 
-def reference_loss(cfg, seq_len: int, batch: int) -> float:
+def reference_loss(cfg) -> float:
     """``loss_fn`` on the trainer's initial parameters and first batch."""
     import jax
 
@@ -108,28 +121,31 @@ def reference_loss(cfg, seq_len: int, batch: int) -> float:
     from repro.models import init_params, loss_fn
 
     params = init_params(jax.random.PRNGKey(SEED), cfg)
-    first = loader_for_model(cfg, seq_len=seq_len, global_batch=batch, seed=SEED).next_batch()
+    first = loader_for_model(cfg, seq_len=SEQ_LEN, global_batch=BATCH, seed=SEED).next_batch()
     loss = jax.jit(lambda p, b: loss_fn(p, b, cfg)[0])(params, first)
     return float(loss)
 
 
-def train_phase(work: Path, devs, *, full: bool = True, seq_len: int = 1024,
-                batch: int = 8, steps: int = 5) -> None:
+def train_argv(strategy: str, steps: int, ckpt: Path) -> list:
+    return [
+        "--arch", ARCH, "--full-config", "--seq-len", str(SEQ_LEN),
+        "--global-batch", str(BATCH), "--steps", str(steps), "--strategy", strategy,
+        "--checkpoint-dir", str(ckpt), "--checkpoint-every", str(steps), "--seed", str(SEED),
+    ]
+
+
+def train_phase(work: Path, devs) -> None:
     import jax
     import numpy as np
 
     from repro.checkpoint import CheckpointStore
-    from repro.configs import get_config, get_smoke_config
+    from repro.configs import get_config
     from repro.launch import train
 
+    steps = TRAIN_STEPS
     ckpt = work / "train_ckpt"
-    argv = [
-        "--arch", ARCH, "--seq-len", str(seq_len), "--global-batch", str(batch),
-        "--steps", str(steps), "--strategy", "hier", "--checkpoint-dir", str(ckpt),
-        "--checkpoint-every", str(steps), "--seed", str(SEED),
-    ] + (["--full-config"] if full else [])
     t0 = time.perf_counter()
-    result = train.main(argv)
+    result = train.main(train_argv("hier", steps, ckpt))
     wall = time.perf_counter() - t0
     losses = [row["loss"] for row in result["metrics"]]
     step_s = [row["step_s"] for row in result["metrics"]]
@@ -144,10 +160,9 @@ def train_phase(work: Path, devs, *, full: bool = True, seq_len: int = 1024,
     check(len(losses) == steps, f"trained {len(losses)} of {steps} steps")
     check(all(math.isfinite(x) for x in losses), f"non-finite loss in {losses}")
 
-    cfg = get_config(ARCH) if full else get_smoke_config(ARCH)
-    ref = reference_loss(cfg, seq_len, batch)
+    ref = reference_loss(get_config(ARCH))
     log(f"[train] step-0 loss {losses[0]:.6f} vs loss_fn {ref:.6f} (rel {rel(losses[0], ref):.2e})")
-    check(rel(losses[0], ref) <= STEP0_RTOL, "step-0 loss disagrees with loss_fn")
+    check(rel(losses[0], ref) <= LOSS_RTOL, "step-0 loss disagrees with loss_fn")
 
     store = CheckpointStore(ckpt)
     check(store.latest_step() == steps, f"latest checkpoint {store.latest_step()} != {steps}")
@@ -160,20 +175,21 @@ def train_phase(work: Path, devs, *, full: bool = True, seq_len: int = 1024,
     log(f"[train] checkpoint step {steps} restored bit-exact ({meta})")
 
 
-def serve_phase(devs, *, full: bool = True, batch: int = 8, prompt: int = 512,
-                gen: int = 64) -> None:
+def serve_phase(devs) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.configs import get_config, get_smoke_config
+    from repro.configs import get_config
     from repro.launch import serve
     from repro.launch.batches import synthetic_prompt_batch
     from repro.models import forward, init_params
 
-    argv = ["--arch", ARCH, "--batch", str(batch), "--prompt-len", str(prompt),
-            "--gen", str(gen), "--seed", str(SEED)] + (["--full-config"] if full else [])
-    out = serve.main(argv)
+    batch, prompt, gen = BATCH, PROMPT_LEN, GEN_TOKENS
+    out = serve.main([
+        "--arch", ARCH, "--full-config", "--batch", str(batch), "--prompt-len", str(prompt),
+        "--gen", str(gen), "--seed", str(SEED),
+    ])
     log(
         f"[serve] prefill {batch}x{prompt}: run {out['prefill_s']:.4f}s, compile "
         f"{out['prefill_compile_s']:.2f}s | decode {gen} steps: run {out['decode_s']:.4f}s "
@@ -182,7 +198,7 @@ def serve_phase(devs, *, full: bool = True, batch: int = 8, prompt: int = 512,
         f"{out['compile_cache_misses']} misses"
     )
     log(f"[serve] {memory_report(devs)}")
-    cfg = get_config(ARCH) if full else get_smoke_config(ARCH)
+    cfg = get_config(ARCH)
     tokens = np.asarray(out["tokens"])
     check(tokens.shape == (batch, gen + 1), f"generated shape {tokens.shape}")
     check(bool(((tokens >= 0) & (tokens < cfg.vocab_size)).all()), "token outside vocabulary")
@@ -204,24 +220,30 @@ def serve_phase(devs, *, full: bool = True, batch: int = 8, prompt: int = 512,
     check(err <= 5e-2, f"decode logits disagree with forward ({err:.2e})")
 
 
-def kernel_phase(*, interpret: bool = False) -> None:
+def kernel_phase() -> None:
+    for shape in KERNEL_LEAVES:
+        kernel_leaf(shape)
+
+
+def kernel_leaf(shape) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels.wan_quant import dequantize, quantize, wan_dequant_ref, wan_quant_ref
+    from repro.kernels.wan_quant import (
+        BLOCK, dequantize, quantize, wan_dequant_ref, wan_quant_ref,
+    )
 
-    shape = (6, 768, 3072)  # distilgpt2's stacked MLP up-projection
     x = jax.random.normal(jax.random.PRNGKey(SEED), shape, jnp.float32) * 0.02
     t0 = time.perf_counter()
-    q_c = quantize.lower(x, interpret=interpret).compile()
+    q_c = quantize.lower(x).compile()
     t_qc = time.perf_counter() - t0
     t0 = time.perf_counter()
     q, s = q_c(x)
     q.block_until_ready()
     t_q = time.perf_counter() - t0
     t0 = time.perf_counter()
-    d_c = dequantize.lower(q, s, orig_shape=shape, interpret=interpret).compile()
+    d_c = dequantize.lower(q, s, orig_shape=shape).compile()
     t_dc = time.perf_counter() - t0
     t0 = time.perf_counter()
     back = d_c(q, s)
@@ -232,22 +254,22 @@ def kernel_phase(*, interpret: bool = False) -> None:
         "host clock)")
 
     rows = x.reshape(-1, shape[-1])
-    q_r, s_r = wan_quant_ref(rows)
+    q_r, s_r = wan_quant_ref(jnp.pad(rows, ((0, 0), (0, (-shape[-1]) % BLOCK))))
     dq = np.abs(np.asarray(q, np.int32) - np.asarray(q_r, np.int32))
-    log(f"[kernels] quant vs ref: max |dq| {dq.max()}, differing {(dq != 0).mean():.2e}")
+    log(f"[kernels] {shape} quant vs ref: max |dq| {dq.max()}, differing {(dq != 0).mean():.2e}")
     check(dq.max() <= 1 and (dq != 0).mean() < 1e-3, "wan_quant disagrees with ref")
     check(np.allclose(np.asarray(s), np.asarray(s_r), rtol=1e-6, atol=0), "scales disagree")
-    back_r = wan_dequant_ref(q, s).reshape(shape)
+    back_r = wan_dequant_ref(q, s)[:, : shape[-1]].reshape(shape)
     check(np.allclose(np.asarray(back), np.asarray(back_r), rtol=1e-6, atol=0),
           "wan_dequant disagrees with ref")
     err = float(jnp.abs(back - x).max())
-    log(f"[kernels] round-trip max error {err:.3e}")
+    log(f"[kernels] {shape} round-trip max error {err:.3e}")
 
 
 # -- four chips ----------------------------------------------------------------
 
 
-def one_chip_losses(work: Path, cfg, *, seq_len: int, batch: int, steps: int):
+def one_chip_losses(work: Path, cfg) -> list:
     """Losses of the same training run on the first device alone."""
     import jax
 
@@ -257,8 +279,10 @@ def one_chip_losses(work: Path, cfg, *, seq_len: int, batch: int, steps: int):
     mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     trainer = GeoTrainer(
         cfg, mesh,
-        trainer_cfg=TrainerConfig(seq_len=seq_len, global_batch=batch, steps=steps,
-                                  strategy="allreduce", checkpoint_every=steps, seed=SEED),
+        trainer_cfg=TrainerConfig(
+            seq_len=SEQ_LEN, global_batch=BATCH, steps=FOUR_CHIP_STEPS,
+            strategy="allreduce", checkpoint_every=FOUR_CHIP_STEPS, seed=SEED,
+        ),
         checkpoint_dir=str(work / "one_chip"),
     )
     result = trainer.run()
@@ -266,29 +290,21 @@ def one_chip_losses(work: Path, cfg, *, seq_len: int, batch: int, steps: int):
     return [row["loss"] for row in result["metrics"]]
 
 
-def four_chip_phase(work: Path, devs, *, full: bool = True, seq_len: int = 1024,
-                    batch: int = 8, steps: int = 3) -> None:
+def four_chip_phase(work: Path, devs) -> None:
     import jax
 
-    from repro.configs import get_config, get_smoke_config
+    from repro.configs import get_config
     from repro.launch import train
 
     check(len(devs) == 4, f"--four-chips needs 4 devices, found {len(devs)}")
-    cfg = get_config(ARCH) if full else get_smoke_config(ARCH)
     t0 = time.perf_counter()
-    ref = one_chip_losses(work, cfg, seq_len=seq_len, batch=batch, steps=steps)
+    ref = one_chip_losses(work, get_config(ARCH))
     log(f"[4chip] one-chip reference losses {ref} ({time.perf_counter() - t0:.2f}s)")
     failures = []
-    for strategy in STRATEGIES:
+    for strategy in SYNCED + (CONTROL,):
         ckpt = work / f"ckpt_{strategy}"
-        argv = [
-            "--arch", ARCH, "--seq-len", str(seq_len), "--global-batch", str(batch),
-            "--steps", str(steps), "--strategy", strategy, "--pods", "2",
-            "--checkpoint-dir", str(ckpt), "--checkpoint-every", str(steps),
-            "--seed", str(SEED),
-        ] + (["--full-config"] if full else [])
         t0 = time.perf_counter()
-        result = train.main(argv)
+        result = train.main(train_argv(strategy, FOUR_CHIP_STEPS, ckpt) + ["--pods", "2"])
         wall = time.perf_counter() - t0
         losses = [row["loss"] for row in result["metrics"]]
         step_s = [row["step_s"] for row in result["metrics"]]
@@ -302,17 +318,23 @@ def four_chip_phase(work: Path, devs, *, full: bool = True, seq_len: int = 1024,
             f"batch on {result['batch_devices']} devices, mesh {mesh_shape}"
         )
         log(f"[4chip] {strategy}: {memory_report(devs)}")
-        bound = LOSS_RTOL.get(strategy)
         errs = [rel(a, b) for a, b in zip(losses, ref)]
+        expect = "within" if strategy in SYNCED else "step 0 within, later outside"
         log(f"[4chip] {strategy}: rel err vs one chip {[f'{e:.2e}' for e in errs]} "
-            f"(bound step 0 {STEP0_RTOL:g}, later {bound if bound else 'not compared'})")
+            f"(expected {expect} {LOSS_RTOL:g})")
         try:
+            check(len(losses) == FOUR_CHIP_STEPS, f"trained {len(losses)} steps")
             check(all(math.isfinite(x) for x in losses), "non-finite loss")
             check(len(params_devs) == 4 and result["batch_devices"] == 4,
                   "state or batch not spread over 4 devices")
-            check(errs[0] <= STEP0_RTOL, "step-0 loss disagrees with one chip")
-            if bound is not None:
-                check(max(errs) <= bound, f"losses disagree with one chip beyond {bound:g}")
+            check(errs[0] <= LOSS_RTOL, "step-0 loss disagrees with one chip")
+            if strategy in SYNCED:
+                check(max(errs) <= LOSS_RTOL,
+                      f"losses disagree with one chip beyond {LOSS_RTOL:g}")
+            else:
+                check(min(errs[1:]) > LOSS_RTOL,
+                      f"the unsynced control matches one chip within {LOSS_RTOL:g}: "
+                      "the bound cannot tell a missing cross-pod exchange")
         except SmokeFailure as e:
             failures.append(f"{strategy}: {e}")
         del result

@@ -4,11 +4,16 @@ The compute hot-spot introduced by the paper's setting: gradients must be
 compressed *at line rate* before the inter-data-center hop (hier_int8
 sync), i.e. the quantizer must stream the full gradient through the VPU
 faster than the WAN drains it.  The grid walks ``row_tile``-row slabs of
-a [rows, lanes] array; each grid step holds whole rows, so its scale
-block ``(row_tile, lanes/256)`` spans the full scale width — the only
-legal TPU block for a scale array narrower than 128 lanes.  Inside a
-slab the 256-lane wire-format blocks (one f32 scale per 256 int8
-payload) are static, 128-aligned lane slices, so absmax reduction and
+a [rows, lanes] array in lane groups of at most 128 wire-format blocks
+(one f32 scale per 256 int8 payload).  A row no wider than 128 blocks is
+one group, whose scale block ``(row_tile, lanes/256)`` spans the full
+scale width; a wider row (a vocabulary-wide unembedding) is walked in
+groups of exactly 128 blocks, whose scale block is 128 lanes wide.  Both
+are legal TPU blocks, and a group bounds the slab in VMEM and the
+unrolled loop at 128 blocks.  The last group may overhang the row: its
+blocks past the end are whole 256-lane blocks, so they read padding and
+their writes are dropped, and no real block is affected.  Inside a slab
+the blocks are static, 128-aligned lane slices, so absmax reduction and
 scaling vectorize with no cross-lane shuffles.  Row tiles are multiples
 of 32 (the int8 sublane tiling) unless one tile covers every row.
 Quantize and dequantize are separate kernels (they run on opposite sides
@@ -28,6 +33,12 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK = 256  # lanes per scale block (wire format)
 ROW_TILE = 256  # rows per grid step
 ROW_ALIGN = 32  # int8 sublane tiling: a partial row tile is a multiple of this
+GROUP = 128  # scale blocks per lane group: one 128-lane tile of scales
+
+
+def lane_group(nblocks: int) -> int:
+    """Scale blocks per grid step along a row of ``nblocks`` blocks."""
+    return min(nblocks, GROUP)
 
 
 def _checked_row_tile(rows: int, row_tile: int) -> int:
@@ -70,19 +81,20 @@ def wan_quant(
         raise ValueError(f"lanes {lanes} is not a multiple of {BLOCK}")
     rt = _checked_row_tile(rows, row_tile)
     nblocks = lanes // BLOCK
+    g = lane_group(nblocks)
     q, s = pl.pallas_call(
-        functools.partial(_quant_kernel, nblocks=nblocks),
-        grid=(rows // rt,),
-        in_specs=[pl.BlockSpec((rt, lanes), lambda r: (r, 0))],
+        functools.partial(_quant_kernel, nblocks=g),
+        grid=(rows // rt, pl.cdiv(nblocks, g)),
+        in_specs=[pl.BlockSpec((rt, g * BLOCK), lambda r, c: (r, c))],
         out_specs=[
-            pl.BlockSpec((rt, lanes), lambda r: (r, 0)),
-            pl.BlockSpec((rt, nblocks), lambda r: (r, 0)),
+            pl.BlockSpec((rt, g * BLOCK), lambda r, c: (r, c)),
+            pl.BlockSpec((rt, g), lambda r, c: (r, c)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
             jax.ShapeDtypeStruct((rows, nblocks), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="wan_quant",
     )(x)
@@ -99,16 +111,17 @@ def wan_dequant(
         raise ValueError(f"lanes {lanes} is not a multiple of {BLOCK}")
     rt = _checked_row_tile(rows, row_tile)
     nblocks = lanes // BLOCK
+    g = lane_group(nblocks)
     return pl.pallas_call(
-        functools.partial(_dequant_kernel, nblocks=nblocks),
-        grid=(rows // rt,),
+        functools.partial(_dequant_kernel, nblocks=g),
+        grid=(rows // rt, pl.cdiv(nblocks, g)),
         in_specs=[
-            pl.BlockSpec((rt, lanes), lambda r: (r, 0)),
-            pl.BlockSpec((rt, nblocks), lambda r: (r, 0)),
+            pl.BlockSpec((rt, g * BLOCK), lambda r, c: (r, c)),
+            pl.BlockSpec((rt, g), lambda r, c: (r, c)),
         ],
-        out_specs=pl.BlockSpec((rt, lanes), lambda r: (r, 0)),
+        out_specs=pl.BlockSpec((rt, g * BLOCK), lambda r, c: (r, c)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="wan_dequant",
     )(q, scales)

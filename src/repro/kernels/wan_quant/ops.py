@@ -16,9 +16,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from .kernel import BLOCK, ROW_ALIGN, ROW_TILE, wan_dequant, wan_quant
+from .kernel import BLOCK, ROW_ALIGN, ROW_TILE, lane_group, wan_dequant, wan_quant
 
-_SLAB_BYTES = 2 << 20  # f32 bytes of one row tile: keeps double-buffered VMEM small
+_SLAB_BYTES = 2 << 20  # f32 bytes of one slab, above the 32-row minimum
 
 
 def _to_rows(x: jnp.ndarray) -> jnp.ndarray:
@@ -29,8 +29,9 @@ def _to_rows(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _row_tile(rows: int, lanes: int) -> int:
-    """Largest legal row tile whose f32 slab fits ``_SLAB_BYTES``."""
-    fit = min(ROW_TILE, _SLAB_BYTES // (4 * lanes)) // ROW_ALIGN * ROW_ALIGN
+    """Largest legal row tile whose f32 slab (one lane group) fits ``_SLAB_BYTES``."""
+    slab_lanes = lane_group(lanes // BLOCK) * BLOCK
+    fit = min(ROW_TILE, _SLAB_BYTES // (4 * slab_lanes)) // ROW_ALIGN * ROW_ALIGN
     rt = max(fit, ROW_ALIGN)
     return rows if rows <= rt else rt
 

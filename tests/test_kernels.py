@@ -157,10 +157,14 @@ class TestWanQuant:
             wan_quant(x, row_tile=rt, interpret=True)
 
     @pytest.mark.parametrize(
-        "shape", [(50257, 768), (6, 768, 2304), (3, 70), (300,), ()]
+        "shape", [(50257, 768), (6, 768, 2304), (3, 70), (300,), (), (4, 50257)]
     )
     def test_ops_pad_rows_and_lanes(self, shape):
-        """quantize/dequantize pad any leaf to legal tiles and strip the padding."""
+        """quantize/dequantize pad any leaf to legal tiles and strip the padding.
+
+        ``(4, 50257)`` is a vocabulary-wide row: 197 scale blocks, walked in
+        one 128-block lane group and one that overhangs the row.
+        """
         x = jax.random.normal(jax.random.PRNGKey(12), shape) * 2
         q, s = quantize(x, interpret=True)
         rows = x.reshape(1, -1) if x.ndim < 2 else x.reshape(-1, x.shape[-1])

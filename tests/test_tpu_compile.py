@@ -4,8 +4,9 @@ The TPU compiler refuses what interpret mode accepts: illegal block
 shapes, unaligned dynamic slices, programs that do not fit HBM.  Each test
 here lowers and compiles for ``v5e:2x2`` devices described by
 ``jax.experimental.topologies`` and checks what the chip's compiler made:
-the Pallas kernels at real widths (a ``tpu_custom_call`` in the HLO), the
-full-width distilgpt2-82m train step on one chip (fits its 16 GB), and a
+the Pallas kernels at real widths (a ``tpu_custom_call`` in the HLO) —
+the WAN quantizers also at the widest trailing dimension of any config's
+parameter leaf, where a slab must still fit VMEM — the full-width distilgpt2-82m train step on one chip (fits its 16 GB), and a
 2-pod ``hier_int8`` step on the four-chip host layout (2, 2, 1).
 
 Nothing runs, so these say nothing about results or speed.  The topology
@@ -21,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import get_config
+from repro.configs import ALL_ARCHS, get_config
 from repro.distributed import init_train_state, make_train_step
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rwkv6_wkv import wkv6_fwd
@@ -74,9 +75,20 @@ def _peak_bytes(compiled) -> int:
     )
 
 
+def _widest_leaf():
+    """The parameter leaf with the widest trailing dimension over every config."""
+    shapes = (
+        leaf.shape for arch in ALL_ARCHS
+        for leaf in jax.tree.leaves(params_specs(get_config(arch)))
+    )
+    return max(shapes, key=lambda shape: shape[-1] if shape else 0)
+
+
 def _kernel_cases(spec):
     leaf = (6, 768, 3072)  # distilgpt2's stacked MLP up-projection
     rows = 6 * 768
+    wide = _widest_leaf()  # rwkv6-7b's unembedding, (4096, 65536)
+    wide_rows, wide_lanes = wide[0], -(-wide[-1] // 256) * 256
     bhsd = (8, 12, 1024, 64)  # distilgpt2 attention at batch 8 x 1024
     btnh = (1, 4096, 64, 64)  # rwkv6-7b: 64 heads of 64
     return {
@@ -84,6 +96,12 @@ def _kernel_cases(spec):
         "wan_dequant": (
             lambda q, s: dequantize(q, s, orig_shape=leaf),
             [spec((rows, 3072), jnp.int8), spec((rows, 12), jnp.float32)],
+        ),
+        "wan_quant_widest": (lambda x: quantize(x), [spec(wide, jnp.float32)]),
+        "wan_dequant_widest": (
+            lambda q, s: dequantize(q, s, orig_shape=wide),
+            [spec((wide_rows, wide_lanes), jnp.int8),
+             spec((wide_rows, wide_lanes // 256), jnp.float32)],
         ),
         "flash_attention_fwd": (
             lambda q, k, v: flash_attention_fwd(q, k, v),
@@ -97,7 +115,11 @@ def _kernel_cases(spec):
     }
 
 
-@pytest.mark.parametrize("kernel", ["wan_quant", "wan_dequant", "flash_attention_fwd", "wkv6_fwd"])
+@pytest.mark.parametrize(
+    "kernel",
+    ["wan_quant", "wan_dequant", "wan_quant_widest", "wan_dequant_widest",
+     "flash_attention_fwd", "wkv6_fwd"],
+)
 def test_kernel_compiles_for_v5e(one_chip, kernel):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
