@@ -42,31 +42,33 @@ def _as_2plus_d(x):
 
 
 def int8_compress(x: jnp.ndarray) -> Int8Compressed:
-    orig_shape = tuple(x.shape)
-    x2 = _as_2plus_d(x.astype(jnp.float32))
-    last = x2.shape[-1]
-    pad = (-last) % BLOCK
-    if pad:
-        x2 = jnp.pad(x2, [(0, 0)] * (x2.ndim - 1) + [(0, pad)])
-    nblocks = x2.shape[-1] // BLOCK
-    blocks = x2.reshape(*x2.shape[:-1], nblocks, BLOCK)
-    absmax = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True)
-    scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(blocks / scale), -127, 127).astype(jnp.int8)
-    return Int8Compressed(
-        values=q.reshape(*x2.shape[:-1], nblocks * BLOCK),
-        scales=scale[..., 0],
-        orig_last=last,
-        orig_shape=orig_shape,
-    )
+    with jax.named_scope("wan_int8"):
+        orig_shape = tuple(x.shape)
+        x2 = _as_2plus_d(x.astype(jnp.float32))
+        last = x2.shape[-1]
+        pad = (-last) % BLOCK
+        if pad:
+            x2 = jnp.pad(x2, [(0, 0)] * (x2.ndim - 1) + [(0, pad)])
+        nblocks = x2.shape[-1] // BLOCK
+        blocks = x2.reshape(*x2.shape[:-1], nblocks, BLOCK)
+        absmax = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True)
+        scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
+        q = jnp.clip(jnp.round(blocks / scale), -127, 127).astype(jnp.int8)
+        return Int8Compressed(
+            values=q.reshape(*x2.shape[:-1], nblocks * BLOCK),
+            scales=scale[..., 0],
+            orig_last=last,
+            orig_shape=orig_shape,
+        )
 
 
 def int8_decompress(c: Int8Compressed) -> jnp.ndarray:
-    lead = c.values.shape[:-1]
-    nblocks = c.values.shape[-1] // BLOCK
-    blocks = c.values.reshape(*lead, nblocks, BLOCK).astype(jnp.float32)
-    full = (blocks * c.scales[..., None]).reshape(*lead, nblocks * BLOCK)
-    return full[..., : c.orig_last].reshape(c.orig_shape)
+    with jax.named_scope("wan_int8"):
+        lead = c.values.shape[:-1]
+        nblocks = c.values.shape[-1] // BLOCK
+        blocks = c.values.reshape(*lead, nblocks, BLOCK).astype(jnp.float32)
+        full = (blocks * c.scales[..., None]).reshape(*lead, nblocks * BLOCK)
+        return full[..., : c.orig_last].reshape(c.orig_shape)
 
 
 def compressed_bytes(c: Int8Compressed) -> int:

@@ -69,8 +69,9 @@ def _f32(grads):
 
 def sync_allreduce(grads, *, axis: str = "pod"):
     """Flat cross-pod mean (paper M2)."""
-    n = jax.lax.psum(1, axis)
-    return jax.tree.map(lambda g: jax.lax.psum(g, axis) / n, _f32(grads))
+    with jax.named_scope("sync"):
+        n = jax.lax.psum(1, axis)
+        return jax.tree.map(lambda g: jax.lax.psum(g, axis) / n, _f32(grads))
 
 
 def sync_hier(grads, *, axis: str = "pod", num_channels: int = 4):
@@ -83,18 +84,19 @@ def sync_hier(grads, *, axis: str = "pod", num_channels: int = 4):
     ``reshape(-1)`` would all-gather every leaf — measured +14 GiB/device
     on phi-3-vision).
     """
-    n = jax.lax.psum(1, axis)
+    with jax.named_scope("sync"):
+        n = jax.lax.psum(1, axis)
 
-    def one(g):
-        if g.ndim == 0 or g.shape[0] < 2:
-            return jax.lax.psum(g, axis) / n
-        parts = [
-            jax.lax.psum(jax.lax.slice_in_dim(g, s, s + size, axis=0), axis)
-            for s, size in _chunk_bounds(g.shape[0], num_channels)
-        ]
-        return jnp.concatenate(parts, axis=0) / n
+        def one(g):
+            if g.ndim == 0 or g.shape[0] < 2:
+                return jax.lax.psum(g, axis) / n
+            parts = [
+                jax.lax.psum(jax.lax.slice_in_dim(g, s, s + size, axis=0), axis)
+                for s, size in _chunk_bounds(g.shape[0], num_channels)
+            ]
+            return jnp.concatenate(parts, axis=0) / n
 
-    return jax.tree.map(one, _f32(grads))
+        return jax.tree.map(one, _f32(grads))
 
 
 def sync_hier_int8(grads, ef, *, axis: str = "pod"):
@@ -105,30 +107,31 @@ def sync_hier_int8(grads, ef, *, axis: str = "pod"):
     fp32 block scales, ~1.6%) cross the WAN.
     Returns (synced grads, new error feedback).
     """
-    n = jax.lax.psum(1, axis)
-    boosted = apply_error_feedback(grads, ef)
+    with jax.named_scope("sync"):
+        n = jax.lax.psum(1, axis)
+        boosted = apply_error_feedback(grads, ef)
 
-    def one(g):
-        c = int8_compress(g)
-        vals = jax.lax.all_gather(c.values, axis)  # (npods, ..., L) int8
-        scls = jax.lax.all_gather(c.scales, axis)  # (npods, ..., L/B) f32
-        nblocks = c.scales.shape[-1]
-        blocks = vals.reshape(*vals.shape[:-1], nblocks, -1).astype(jnp.float32)
-        deq = (blocks * scls[..., None]).reshape(vals.shape).sum(0)
-        mean = deq[..., : c.orig_last].reshape(c.orig_shape) / n
-        local_deq = int8_decompress(c)
-        return mean, local_deq
+        def one(g):
+            c = int8_compress(g)
+            vals = jax.lax.all_gather(c.values, axis)  # (npods, ..., L) int8
+            scls = jax.lax.all_gather(c.scales, axis)  # (npods, ..., L/B) f32
+            nblocks = c.scales.shape[-1]
+            blocks = vals.reshape(*vals.shape[:-1], nblocks, -1).astype(jnp.float32)
+            deq = (blocks * scls[..., None]).reshape(vals.shape).sum(0)
+            mean = deq[..., : c.orig_last].reshape(c.orig_shape) / n
+            local_deq = int8_decompress(c)
+            return mean, local_deq
 
-    flat, treedef = jax.tree.flatten(boosted)
-    synced, transmitted = [], []
-    for g in flat:
-        m, t = one(g)
-        synced.append(m)
-        transmitted.append(t)
-    synced = jax.tree.unflatten(treedef, synced)
-    transmitted = jax.tree.unflatten(treedef, transmitted)
-    new_ef = residual(boosted, transmitted)
-    return synced, new_ef
+        flat, treedef = jax.tree.flatten(boosted)
+        synced, transmitted = [], []
+        for g in flat:
+            m, t = one(g)
+            synced.append(m)
+            transmitted.append(t)
+        synced = jax.tree.unflatten(treedef, synced)
+        transmitted = jax.tree.unflatten(treedef, transmitted)
+        new_ef = residual(boosted, transmitted)
+        return synced, new_ef
 
 
 def sync_ps(grads, params, apply_update: Callable, *, axis: str = "pod"):
@@ -145,17 +148,18 @@ def sync_ps(grads, params, apply_update: Callable, *, axis: str = "pod"):
     values (identical computation everywhere; non-0 pods discard).
     Returns the broadcast updated params.
     """
-    # push: server receives every pod's gradients
-    gathered = jax.tree.map(lambda g: jax.lax.all_gather(g, axis), grads)
-    g_mean = jax.tree.map(lambda g: g.mean(0), gathered)
-    updated = apply_update(g_mean)
-    # pull: only the server's copy survives the broadcast
-    is_server = (jax.lax.axis_index(axis) == 0).astype(jnp.float32)
+    with jax.named_scope("sync"):
+        # push: server receives every pod's gradients
+        gathered = jax.tree.map(lambda g: jax.lax.all_gather(g, axis), grads)
+        g_mean = jax.tree.map(lambda g: g.mean(0), gathered)
+        updated = apply_update(g_mean)
+        # pull: only the server's copy survives the broadcast
+        is_server = (jax.lax.axis_index(axis) == 0).astype(jnp.float32)
 
-    def bcast(u):
-        return jax.lax.psum(u * is_server.astype(u.dtype), axis)
+        def bcast(u):
+            return jax.lax.psum(u * is_server.astype(u.dtype), axis)
 
-    return jax.tree.map(bcast, updated)
+        return jax.tree.map(bcast, updated)
 
 
 def sync_local(grads):

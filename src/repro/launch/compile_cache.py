@@ -1,9 +1,17 @@
 """Persistent compilation cache for the launchers, placed from outside.
 
 ``JAX_COMPILATION_CACHE_DIR``, where set, is read by JAX itself and this
-module sets nothing.  Otherwise the cache goes to ``<checkout>/.jax_cache``:
-a fixed path, because the directory is part of what a later run must find
-again (a temporary or per-process path would never hit).
+module sets no directory.  Otherwise the cache goes to
+``<checkout>/.jax_cache``: a fixed path, because the directory is part of
+what a later run must find again (a temporary or per-process path would
+never hit).
+
+Either way the cache key holds each program's metadata: its named scopes
+and source lines.  JAX leaves them out by default, and a program loaded from
+the cache then carries the metadata of whichever compile stored it, so a
+profile would name its operations by another version's scopes.  Source
+files are named relative to the checkout, so that the key does not depend
+on where the checkout lies.
 
 :class:`CompileStats` counts, while it is entered, the cache hits and
 misses and the seconds spent in the backend compiler, so a launcher can
@@ -13,6 +21,7 @@ say whether a second run compiled anything.
 from __future__ import annotations
 
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -28,6 +37,8 @@ _COMPILE = "/jax/core/compile/backend_compile_duration"
 
 def enable_compile_cache() -> str:
     """Turn the persistent cache on; returns the directory it uses."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex", "^" + re.escape(f"{CHECKOUT}{os.sep}"))
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -267,9 +267,10 @@ def attention_forward(
     y = _out_proj(params, out, cfg)
     if not return_cache:
         return y, None
-    cache = make_cache_from_prefill(
-        k, v, positions, window=window, max_len=cache_len or S
-    )
+    with jax.named_scope("kv_cache"):
+        cache = make_cache_from_prefill(
+            k, v, positions, window=window, max_len=cache_len or S
+        )
     return y, cache
 
 
@@ -330,11 +331,12 @@ def attention_decode(
     k_new = apply_rope(k_new, pos_arr, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
     size = cache["k"].shape[1]
     slot = position % size  # rolling for windows; affine for full caches
-    k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
-    pos = jax.lax.dynamic_update_slice_in_dim(
-        cache["pos"], pos_arr, slot, axis=0
-    )
+    with jax.named_scope("kv_cache"):
+        k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
+        v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
+        pos = jax.lax.dynamic_update_slice_in_dim(
+            cache["pos"], pos_arr, slot, axis=0
+        )
     out = sdpa(
         q, k, v,
         q_positions=pos_arr, k_positions=pos,

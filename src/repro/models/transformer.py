@@ -13,6 +13,11 @@ Entry points (all pure functions over a params pytree):
 * :func:`prefill`      — forward + per-layer KV/recurrent caches
 * :func:`decode_step`  — one token through the cache pytree
 * :func:`loss_fn`      — next-token CE (+ router aux, z-loss)
+
+Named scopes (``jax.named_scope``: compile-time metadata, which a profile
+shows as each operation's path) mark ``embed``, ``layers`` (the stack's own
+work), ``attention`` and ``ffn`` in each block, ``head``, ``loss``,
+``kv_cache`` and the whole of ``prefill`` and ``decode``.
 """
 
 from __future__ import annotations
@@ -111,24 +116,28 @@ def _block_train(params: Params, x, kind: str, cfg: ModelConfig, positions):
     """One layer (training/prefill, no cache). Returns (x, aux, cache)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in (ATTN, LOCAL):
-        h = apply_norm(params["norm1"], x, cfg)
-        attn_out, _ = attention_forward(
-            params["attn"], h, cfg, window=_layer_window(kind, cfg), positions=positions
-        )
+        with jax.named_scope("attention"):
+            h = apply_norm(params["norm1"], x, cfg)
+            attn_out, _ = attention_forward(
+                params["attn"], h, cfg, window=_layer_window(kind, cfg), positions=positions
+            )
         x = x + attn_out
-        h = apply_norm(params["norm2"], x, cfg)
-        if cfg.moe is not None and kind == ATTN:
-            ffn_out, aux = moe_ffn(params["ffn"], h, cfg)
-        else:
-            ffn_out = dense_ffn(params["ffn"], h, cfg)
+        with jax.named_scope("ffn"):
+            h = apply_norm(params["norm2"], x, cfg)
+            if cfg.moe is not None and kind == ATTN:
+                ffn_out, aux = moe_ffn(params["ffn"], h, cfg)
+            else:
+                ffn_out = dense_ffn(params["ffn"], h, cfg)
         x = x + ffn_out
     elif kind == RECURRENT:
         b = x.shape[0]
         h = apply_norm(params["norm1"], x, cfg)
         rec_out, _ = rglru_block(params["rec"], h, cfg, state=init_rglru_state(cfg, b))
         x = x + rec_out
-        h = apply_norm(params["norm2"], x, cfg)
-        x = x + dense_ffn(params["ffn"], h, cfg)
+        with jax.named_scope("ffn"):
+            h = apply_norm(params["norm2"], x, cfg)
+            ffn_out = dense_ffn(params["ffn"], h, cfg)
+        x = x + ffn_out
     elif kind == RWKV:
         b = x.shape[0]
         st = init_rwkv_state(cfg, b)
@@ -148,18 +157,20 @@ def _block_train(params: Params, x, kind: str, cfg: ModelConfig, positions):
 def _block_prefill(params: Params, x, kind: str, cfg: ModelConfig, positions, max_len: int):
     """One layer, returning its decode cache."""
     if kind in (ATTN, LOCAL):
-        h = apply_norm(params["norm1"], x, cfg)
-        attn_out, cache = attention_forward(
-            params["attn"], h, cfg,
-            window=_layer_window(kind, cfg), positions=positions,
-            return_cache=True, cache_len=max_len,
-        )
+        with jax.named_scope("attention"):
+            h = apply_norm(params["norm1"], x, cfg)
+            attn_out, cache = attention_forward(
+                params["attn"], h, cfg,
+                window=_layer_window(kind, cfg), positions=positions,
+                return_cache=True, cache_len=max_len,
+            )
         x = x + attn_out
-        h = apply_norm(params["norm2"], x, cfg)
-        if cfg.moe is not None and kind == ATTN:
-            ffn_out, _ = moe_ffn(params["ffn"], h, cfg)
-        else:
-            ffn_out = dense_ffn(params["ffn"], h, cfg)
+        with jax.named_scope("ffn"):
+            h = apply_norm(params["norm2"], x, cfg)
+            if cfg.moe is not None and kind == ATTN:
+                ffn_out, _ = moe_ffn(params["ffn"], h, cfg)
+            else:
+                ffn_out = dense_ffn(params["ffn"], h, cfg)
         x = x + ffn_out
         return x, cache
     if kind == RECURRENT:
@@ -167,8 +178,10 @@ def _block_prefill(params: Params, x, kind: str, cfg: ModelConfig, positions, ma
         h = apply_norm(params["norm1"], x, cfg)
         rec_out, state = rglru_block(params["rec"], h, cfg, state=init_rglru_state(cfg, b))
         x = x + rec_out
-        h = apply_norm(params["norm2"], x, cfg)
-        x = x + dense_ffn(params["ffn"], h, cfg)
+        with jax.named_scope("ffn"):
+            h = apply_norm(params["norm2"], x, cfg)
+            ffn_out = dense_ffn(params["ffn"], h, cfg)
+        x = x + ffn_out
         return x, state
     if kind == RWKV:
         b = x.shape[0]
@@ -188,23 +201,27 @@ def _block_prefill(params: Params, x, kind: str, cfg: ModelConfig, positions, ma
 def _block_decode(params: Params, x_t, cache, kind: str, cfg: ModelConfig, position):
     """One layer, one token. Returns (x_t, new_cache)."""
     if kind in (ATTN, LOCAL):
-        h = apply_norm(params["norm1"], x_t, cfg)
-        attn_out, cache = attention_decode(
-            params["attn"], h, cache, cfg, position, window=_layer_window(kind, cfg)
-        )
+        with jax.named_scope("attention"):
+            h = apply_norm(params["norm1"], x_t, cfg)
+            attn_out, cache = attention_decode(
+                params["attn"], h, cache, cfg, position, window=_layer_window(kind, cfg)
+            )
         x_t = x_t + attn_out
-        h = apply_norm(params["norm2"], x_t, cfg)
-        if cfg.moe is not None and kind == ATTN:
-            ffn_out, _ = moe_ffn(params["ffn"], h, cfg)
-        else:
-            ffn_out = dense_ffn(params["ffn"], h, cfg)
+        with jax.named_scope("ffn"):
+            h = apply_norm(params["norm2"], x_t, cfg)
+            if cfg.moe is not None and kind == ATTN:
+                ffn_out, _ = moe_ffn(params["ffn"], h, cfg)
+            else:
+                ffn_out = dense_ffn(params["ffn"], h, cfg)
         return x_t + ffn_out, cache
     if kind == RECURRENT:
         h = apply_norm(params["norm1"], x_t, cfg)
         rec_out, state = rglru_block(params["rec"], h, cfg, state=cache)
         x_t = x_t + rec_out
-        h = apply_norm(params["norm2"], x_t, cfg)
-        return x_t + dense_ffn(params["ffn"], h, cfg), state
+        with jax.named_scope("ffn"):
+            h = apply_norm(params["norm2"], x_t, cfg)
+            ffn_out = dense_ffn(params["ffn"], h, cfg)
+        return x_t + ffn_out, state
     if kind == RWKV:
         h = apply_norm(params["norm1"], x_t, cfg)
         tm_out, shift_att, wkv = time_mix(
@@ -228,26 +245,28 @@ def embed_inputs(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig
     from repro.distributed.act_sharding import shard_activations
 
     dt = cfg.compute_dtype
-    if cfg.frontend == "frame":
-        x = batch["frame_embeds"].astype(dt) @ params["frontend_proj"].astype(dt)
-    else:
-        x = params["embed"].astype(dt)[batch["tokens"]]
-        if cfg.frontend == "patch":
-            patches = batch["patch_embeds"].astype(dt) @ params["frontend_proj"].astype(dt)
-            x = jnp.concatenate([patches, x], axis=1)
-    x = shard_activations(x)  # batch dim -> ("pod",)"data" per active context
-    positions = jnp.arange(x.shape[1])
+    with jax.named_scope("embed"):
+        if cfg.frontend == "frame":
+            x = batch["frame_embeds"].astype(dt) @ params["frontend_proj"].astype(dt)
+        else:
+            x = params["embed"].astype(dt)[batch["tokens"]]
+            if cfg.frontend == "patch":
+                patches = batch["patch_embeds"].astype(dt) @ params["frontend_proj"].astype(dt)
+                x = jnp.concatenate([patches, x], axis=1)
+        x = shard_activations(x)  # batch dim -> ("pod",)"data" per active context
+        positions = jnp.arange(x.shape[1])
     return x, positions
 
 
 def unembed(params: Params, x, cfg: ModelConfig):
     dt = cfg.compute_dtype
-    h = apply_norm(params["final_norm"], x, cfg)
-    if cfg.tie_embeddings:
-        logits = h @ params["embed"].astype(dt).T
-    else:
-        logits = h @ params["unembed"].astype(dt)
-    return softcap(logits, cfg.logits_softcap)
+    with jax.named_scope("head"):
+        h = apply_norm(params["final_norm"], x, cfg)
+        if cfg.tie_embeddings:
+            logits = h @ params["embed"].astype(dt).T
+        else:
+            logits = h @ params["unembed"].astype(dt)
+        return softcap(logits, cfg.logits_softcap)
 
 
 # -- full-stack passes -----------------------------------------------------------
@@ -279,17 +298,20 @@ def forward(params: Params, batch, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.n
         return (shard_activations(x), aux), None
 
     aux = jnp.zeros((), jnp.float32)
-    if cfg.num_groups > 0:
-        body = _maybe_remat(group_body, cfg)
-        if cfg.scan_layers:
-            (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
-        else:  # unrolled: used by the dry-run cost probes
-            for i in range(cfg.num_groups):
-                slot_i = jax.tree.map(lambda a, i=i: a[i], params["groups"])
-                (x, aux), _ = body((x, aux), slot_i)
-    for i, kind in enumerate(cfg.remainder):
-        x, a = _block_train(params["remainder"][i], x, kind, cfg, positions)
-        aux = aux + a
+    # "layers": the layer stack's own work, such as the scan's slicing of
+    # stacked weights and its stacking of residuals, outside any one block
+    with jax.named_scope("layers"):
+        if cfg.num_groups > 0:
+            body = _maybe_remat(group_body, cfg)
+            if cfg.scan_layers:
+                (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
+            else:  # unrolled: used by the dry-run cost probes
+                for i in range(cfg.num_groups):
+                    slot_i = jax.tree.map(lambda a, i=i: a[i], params["groups"])
+                    (x, aux), _ = body((x, aux), slot_i)
+        for i, kind in enumerate(cfg.remainder):
+            x, a = _block_train(params["remainder"][i], x, kind, cfg, positions)
+            aux = aux + a
     logits = unembed(params, x, cfg)
     return logits, aux
 
@@ -298,60 +320,63 @@ def loss_fn(params: Params, batch, cfg: ModelConfig):
     """Next-token cross-entropy with label masking and aux losses."""
     logits, aux = forward(params, batch, cfg)
     labels = batch["labels"]
-    # standard causal shift: logits[t] predicts labels[t]
-    logits = logits[:, : labels.shape[1], :]
-    mask = (labels != IGNORE_LABEL).astype(jnp.float32)
-    safe_labels = jnp.maximum(labels, 0)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    token_ll = jnp.take_along_axis(logp, safe_labels[..., None], axis=-1)[..., 0]
-    denom = jnp.maximum(mask.sum(), 1.0)
-    ce = -(token_ll * mask).sum() / denom
-    total = ce
-    if cfg.z_loss:
-        logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-        zl = cfg.z_loss * jnp.mean(jnp.square(logz) * mask)
-        total = total + zl
-    if cfg.moe is not None:
-        total = total + cfg.moe.router_aux_coef * aux
+    with jax.named_scope("loss"):
+        # standard causal shift: logits[t] predicts labels[t]
+        logits = logits[:, : labels.shape[1], :]
+        mask = (labels != IGNORE_LABEL).astype(jnp.float32)
+        safe_labels = jnp.maximum(labels, 0)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        token_ll = jnp.take_along_axis(logp, safe_labels[..., None], axis=-1)[..., 0]
+        denom = jnp.maximum(mask.sum(), 1.0)
+        ce = -(token_ll * mask).sum() / denom
+        total = ce
+        if cfg.z_loss:
+            logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+            zl = cfg.z_loss * jnp.mean(jnp.square(logz) * mask)
+            total = total + zl
+        if cfg.moe is not None:
+            total = total + cfg.moe.router_aux_coef * aux
     metrics = {"ce": ce, "aux": aux, "tokens": denom}
     return total, metrics
 
 
 def prefill(params: Params, batch, cfg: ModelConfig, *, max_len: Optional[int] = None):
     """Forward + caches. Returns (last-position logits [B, V], cache pytree)."""
-    x, positions = embed_inputs(params, batch, cfg)
-    max_len = max_len or x.shape[1]
+    with jax.named_scope("prefill"):
+        x, positions = embed_inputs(params, batch, cfg)
+        max_len = max_len or x.shape[1]
 
-    from repro.distributed.act_sharding import shard_activations
+        from repro.distributed.act_sharding import shard_activations
 
-    def group_body(x, slot_params):
-        caches = {}
-        for s, kind in enumerate(cfg.pattern):
-            x, cache = _block_prefill(
-                slot_params[f"slot{s}"], x, kind, cfg, positions, max_len
-            )
-            caches[f"slot{s}"] = cache
-        return shard_activations(x), caches
+        def group_body(x, slot_params):
+            caches = {}
+            for s, kind in enumerate(cfg.pattern):
+                x, cache = _block_prefill(
+                    slot_params[f"slot{s}"], x, kind, cfg, positions, max_len
+                )
+                caches[f"slot{s}"] = cache
+            return shard_activations(x), caches
 
-    cache: Params = {}
-    if cfg.num_groups > 0:
-        if cfg.scan_layers:
-            x, cache["groups"] = jax.lax.scan(group_body, x, params["groups"])
-        else:
-            caches = []
-            for i in range(cfg.num_groups):
-                slot_i = jax.tree.map(lambda a, i=i: a[i], params["groups"])
-                x, c = group_body(x, slot_i)
-                caches.append(c)
-            cache["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
-    rem = []
-    for i, kind in enumerate(cfg.remainder):
-        x, c = _block_prefill(params["remainder"][i], x, kind, cfg, positions, max_len)
-        rem.append(c)
-    if rem:
-        cache["remainder"] = rem
-    logits = unembed(params, x[:, -1:, :], cfg)[:, 0, :]
-    return logits, cache
+        cache: Params = {}
+        with jax.named_scope("layers"):
+            if cfg.num_groups > 0:
+                if cfg.scan_layers:
+                    x, cache["groups"] = jax.lax.scan(group_body, x, params["groups"])
+                else:
+                    caches = []
+                    for i in range(cfg.num_groups):
+                        slot_i = jax.tree.map(lambda a, i=i: a[i], params["groups"])
+                        x, c = group_body(x, slot_i)
+                        caches.append(c)
+                    cache["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
+            rem = []
+            for i, kind in enumerate(cfg.remainder):
+                x, c = _block_prefill(params["remainder"][i], x, kind, cfg, positions, max_len)
+                rem.append(c)
+        if rem:
+            cache["remainder"] = rem
+        logits = unembed(params, x[:, -1:, :], cfg)[:, 0, :]
+        return logits, cache
 
 
 def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
@@ -384,45 +409,48 @@ def decode_step(params: Params, tokens_t, cache, cfg: ModelConfig, position):
     "frame" stub); position: scalar absolute position.
     Returns (logits [B, V], new cache).
     """
-    from repro.distributed.act_sharding import shard_activations
+    with jax.named_scope("decode"):
+        from repro.distributed.act_sharding import shard_activations
 
-    dt = cfg.compute_dtype
-    if cfg.frontend == "frame":
-        x_t = tokens_t.astype(dt) @ params["frontend_proj"].astype(dt)
-    else:
-        x_t = params["embed"].astype(dt)[tokens_t][:, None, :]
-    x_t = shard_activations(x_t)
+        dt = cfg.compute_dtype
+        with jax.named_scope("embed"):
+            if cfg.frontend == "frame":
+                x_t = tokens_t.astype(dt) @ params["frontend_proj"].astype(dt)
+            else:
+                x_t = params["embed"].astype(dt)[tokens_t][:, None, :]
+            x_t = shard_activations(x_t)
 
-    def group_body(x_t, xs):
-        slot_params, slot_cache = xs
-        new_caches = {}
-        for s, kind in enumerate(cfg.pattern):
-            x_t, nc = _block_decode(
-                slot_params[f"slot{s}"], x_t, slot_cache[f"slot{s}"], kind, cfg, position
-            )
-            new_caches[f"slot{s}"] = nc
-        return x_t, new_caches
+        def group_body(x_t, xs):
+            slot_params, slot_cache = xs
+            new_caches = {}
+            for s, kind in enumerate(cfg.pattern):
+                x_t, nc = _block_decode(
+                    slot_params[f"slot{s}"], x_t, slot_cache[f"slot{s}"], kind, cfg, position
+                )
+                new_caches[f"slot{s}"] = nc
+            return x_t, new_caches
 
-    new_cache: Params = {}
-    if cfg.num_groups > 0:
-        if cfg.scan_layers:
-            x_t, new_cache["groups"] = jax.lax.scan(
-                group_body, x_t, (params["groups"], cache["groups"])
-            )
-        else:
-            caches = []
-            for i in range(cfg.num_groups):
-                take_i = lambda a, i=i: jax.tree.map(lambda v: v[i], a)
-                x_t, c = group_body(x_t, (take_i(params["groups"]), take_i(cache["groups"])))
-                caches.append(c)
-            new_cache["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
-    if cfg.remainder:
-        rem = []
-        for i, kind in enumerate(cfg.remainder):
-            x_t, nc = _block_decode(
-                params["remainder"][i], x_t, cache["remainder"][i], kind, cfg, position
-            )
-            rem.append(nc)
-        new_cache["remainder"] = rem
-    logits = unembed(params, x_t, cfg)[:, 0, :]
-    return logits, new_cache
+        new_cache: Params = {}
+        with jax.named_scope("layers"):
+            if cfg.num_groups > 0:
+                if cfg.scan_layers:
+                    x_t, new_cache["groups"] = jax.lax.scan(
+                        group_body, x_t, (params["groups"], cache["groups"])
+                    )
+                else:
+                    caches = []
+                    for i in range(cfg.num_groups):
+                        take_i = lambda a, i=i: jax.tree.map(lambda v: v[i], a)
+                        x_t, c = group_body(x_t, (take_i(params["groups"]), take_i(cache["groups"])))
+                        caches.append(c)
+                    new_cache["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
+            if cfg.remainder:
+                rem = []
+                for i, kind in enumerate(cfg.remainder):
+                    x_t, nc = _block_decode(
+                        params["remainder"][i], x_t, cache["remainder"][i], kind, cfg, position
+                    )
+                    rem.append(nc)
+                new_cache["remainder"] = rem
+        logits = unembed(params, x_t, cfg)[:, 0, :]
+        return logits, new_cache

@@ -71,33 +71,34 @@ def adamw_update(
     cfg: AdamWConfig, grads, state: AdamWState, params
 ) -> Tuple[Any, AdamWState, Dict[str, jnp.ndarray]]:
     """One AdamW step. Returns (new_params, new_state, metrics)."""
-    metrics: Dict[str, jnp.ndarray] = {}
-    grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-    if cfg.clip_norm is not None:
-        grads, norm = clip_by_global_norm(grads, cfg.clip_norm)
-        metrics["grad_norm"] = norm
-    step = state.step + 1
-    lr = schedule_lr(cfg, step)
-    metrics["lr"] = lr
-    b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
+    with jax.named_scope("adamw"):
+        metrics: Dict[str, jnp.ndarray] = {}
+        grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+        if cfg.clip_norm is not None:
+            grads, norm = clip_by_global_norm(grads, cfg.clip_norm)
+            metrics["grad_norm"] = norm
+        step = state.step + 1
+        lr = schedule_lr(cfg, step)
+        metrics["lr"] = lr
+        b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v):
-        m = cfg.b1 * m + (1 - cfg.b1) * g
-        v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
-        mhat = m / b1c
-        vhat = v / b2c
-        delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        if cfg.weight_decay:
-            delta = delta + cfg.weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
+        def upd(p, g, m, v):
+            m = cfg.b1 * m + (1 - cfg.b1) * g
+            v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
+            mhat = m / b1c
+            vhat = v / b2c
+            delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
+            if cfg.weight_decay:
+                delta = delta + cfg.weight_decay * p.astype(jnp.float32)
+            return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
 
-    flat_p, treedef = jax.tree.flatten(params)
-    flat_g = treedef.flatten_up_to(grads)
-    flat_m = treedef.flatten_up_to(state.m)
-    flat_v = treedef.flatten_up_to(state.v)
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = jax.tree.unflatten(treedef, [o[0] for o in out])
-    new_m = jax.tree.unflatten(treedef, [o[1] for o in out])
-    new_v = jax.tree.unflatten(treedef, [o[2] for o in out])
-    return new_p, AdamWState(step=step, m=new_m, v=new_v), metrics
+        flat_p, treedef = jax.tree.flatten(params)
+        flat_g = treedef.flatten_up_to(grads)
+        flat_m = treedef.flatten_up_to(state.m)
+        flat_v = treedef.flatten_up_to(state.v)
+        out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = jax.tree.unflatten(treedef, [o[0] for o in out])
+        new_m = jax.tree.unflatten(treedef, [o[1] for o in out])
+        new_v = jax.tree.unflatten(treedef, [o[2] for o in out])
+        return new_p, AdamWState(step=step, m=new_m, v=new_v), metrics

@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import AsyncCheckpointer, CheckpointStore
 from repro.core.geo import GeoFabric, SyncOptions
@@ -32,6 +33,19 @@ from repro.optim import AdamWConfig, DilocoConfig
 
 from .failure import HeartbeatMonitor, optimal_checkpoint_interval, plan_recovery
 from .straggler import StragglerMonitor
+
+# Host spans of the loop, written into a profiler trace when one is recording
+# (``jax.profiler``) and costing about a microsecond each otherwise.  Each step
+# is one ``STEP`` span; inside it the phases below follow one another, in this
+# order, without overlap.
+SPAN_STEP = "repro.train.step"
+SPAN_EVENTS = "repro.train.events"  # scenario events, on steps that have any
+SPAN_FEED = "repro.train.feed"  # next batch from the loader, and its device_put
+SPAN_DISPATCH = "repro.train.dispatch"  # the step call, until it returns
+SPAN_FETCH = "repro.train.fetch"  # loss and grad norm to the host: the wait for the device
+SPAN_BOOKKEEP = "repro.train.bookkeep"  # heartbeats, stragglers, recovery, row, log, cadence
+SPAN_CALLBACK = "repro.train.callback"  # the caller's on_step
+SPAN_CHECKPOINT = "repro.train.checkpoint"  # a save when one is due, and the final wait
 
 
 @dataclasses.dataclass
@@ -204,59 +218,70 @@ class GeoTrainer:
         batch = {}
         with self.mesh:
             for step in range(start, tc.steps):
-                for ev in events_by_step.get(step, ()):
-                    apply_event(ev, self.geo, scenario_rollup, straggler_noop)
-                batch = jax.device_put(self.loader.next_batch(), self.shardings["batch"])
-                t0 = time.time()
-                params, state, metrics = self.step_fn(params, state, batch)
-                loss = float(metrics["loss"])
-                dt = time.time() - t0
-                t_step_ewma = dt if t_step_ewma is None else 0.8 * t_step_ewma + 0.2 * dt
+                with StepTraceAnnotation(SPAN_STEP, step_num=step):
+                    if step in events_by_step:
+                        with TraceAnnotation(SPAN_EVENTS):
+                            for ev in events_by_step[step]:
+                                apply_event(ev, self.geo, scenario_rollup, straggler_noop)
+                    with TraceAnnotation(SPAN_FEED):
+                        batch = jax.device_put(self.loader.next_batch(), self.shardings["batch"])
+                    t0 = time.time()
+                    with TraceAnnotation(SPAN_DISPATCH):
+                        params, state, metrics = self.step_fn(params, state, batch)
+                    with TraceAnnotation(SPAN_FETCH):
+                        loss = float(metrics["loss"])
+                        grad_norm = float(metrics.get("grad_norm", 0.0))
+                    dt = time.time() - t0
 
-                sim_ms += interval_ms
-                for pod in self.heartbeats.workers:
-                    if inject_failure_at is not None and step >= inject_failure_at and pod == "pod1":
-                        continue  # pod1 goes silent
-                    self.heartbeats.heartbeat(pod, sim_ms)
-                    self.stragglers.record(pod, dt)
-                # +1 ms epsilon: a pod missing detect_mult consecutive beats
-                # is declared dead on exactly that step
-                dead = self.heartbeats.poll(sim_ms + 1.0)
-                if dead:
-                    plan = plan_recovery(
-                        step=step,
-                        last_checkpoint_step=last_ckpt,
-                        step_time_s=t_step_ewma or dt,
-                        detect_time_ms=self.heartbeats.detect_time_ms(),
-                        checkpoint_bytes=self.grad_bytes * 3,
-                    )
-                    recovery_drills.append({"step": step, "dead": dead, "plan": dataclasses.asdict(plan)})
-                    inject_failure_at = None  # handled
+                    with TraceAnnotation(SPAN_BOOKKEEP):
+                        t_step_ewma = dt if t_step_ewma is None else 0.8 * t_step_ewma + 0.2 * dt
+                        sim_ms += interval_ms
+                        for pod in self.heartbeats.workers:
+                            if inject_failure_at is not None and step >= inject_failure_at and pod == "pod1":
+                                continue  # pod1 goes silent
+                            self.heartbeats.heartbeat(pod, sim_ms)
+                            self.stragglers.record(pod, dt)
+                        # +1 ms epsilon: a pod missing detect_mult consecutive beats
+                        # is declared dead on exactly that step
+                        dead = self.heartbeats.poll(sim_ms + 1.0)
+                        if dead:
+                            plan = plan_recovery(
+                                step=step,
+                                last_checkpoint_step=last_ckpt,
+                                step_time_s=t_step_ewma or dt,
+                                detect_time_ms=self.heartbeats.detect_time_ms(),
+                                checkpoint_bytes=self.grad_bytes * 3,
+                            )
+                            recovery_drills.append({"step": step, "dead": dead, "plan": dataclasses.asdict(plan)})
+                            inject_failure_at = None  # handled
 
-                row = {
-                    "step": step,
-                    "loss": loss,
-                    "step_s": dt,
-                    "grad_norm": float(metrics.get("grad_norm", 0.0)),
-                    "wan_s_est": wan_cost.amortized_seconds if wan_cost else 0.0,
-                }
-                self.metrics_log.append(row)
-                if on_step:
-                    on_step(step, row)
-                if step % tc.log_every == 0:
-                    print(
-                        f"step {step:5d} loss {loss:7.4f} "
-                        f"({dt:5.2f}s compute, +{row['wan_s_est']:.2f}s WAN est "
-                        f"[{tc.strategy}])",
-                        flush=True,
-                    )
-                interval = self._ckpt_interval(t_step_ewma or dt)
-                if (step + 1) % max(interval, 1) == 0 or step == tc.steps - 1:
-                    self.ckpt.save(
-                        step + 1, (params, state), metadata={"data_step": step + 1}
-                    )
-                    last_ckpt = step + 1
-        self.ckpt.wait()
+                        row = {
+                            "step": step,
+                            "loss": loss,
+                            "step_s": dt,
+                            "grad_norm": grad_norm,
+                            "wan_s_est": wan_cost.amortized_seconds if wan_cost else 0.0,
+                        }
+                        self.metrics_log.append(row)
+                        if step % tc.log_every == 0:
+                            print(
+                                f"step {step:5d} loss {loss:7.4f} "
+                                f"({dt:5.2f}s dispatch+fetch, +{row['wan_s_est']:.2f}s WAN est "
+                                f"[{tc.strategy}])",
+                                flush=True,
+                            )
+                        interval = self._ckpt_interval(t_step_ewma or dt)
+                    if on_step:
+                        with TraceAnnotation(SPAN_CALLBACK):
+                            on_step(step, row)
+                    if (step + 1) % max(interval, 1) == 0 or step == tc.steps - 1:
+                        with TraceAnnotation(SPAN_CHECKPOINT):
+                            self.ckpt.save(
+                                step + 1, (params, state), metadata={"data_step": step + 1}
+                            )
+                        last_ckpt = step + 1
+        with TraceAnnotation(SPAN_CHECKPOINT):
+            self.ckpt.wait()
         return {
             "final_loss": self.metrics_log[-1]["loss"] if self.metrics_log else None,
             "params": params,
@@ -268,9 +293,6 @@ class GeoTrainer:
             "recovery_drills": recovery_drills,
             "sync_efficiency": self.stragglers.sync_efficiency(),
             "last_checkpoint": last_ckpt,
-            "wan_phases": (
-                {p.name: p.duration_s for p in wan_cost.phases} if wan_cost else {}
-            ),
             "scenario_recoveries": (
                 [
                     {"mechanism": t.mechanism, "recovery_ms": t.recovery_ms}

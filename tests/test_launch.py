@@ -162,6 +162,28 @@ class TestCompileCache:
     """The persistent compile cache is placed from outside, else at a fixed
     path in the checkout (never a temporary or per-process one)."""
 
+    @pytest.fixture(autouse=True)
+    def _restore_config(self):
+        names = ("jax_compilation_cache_dir", "jax_compilation_cache_include_metadata_in_key",
+                 "jax_hlo_source_file_canonicalization_regex")
+        saved = {n: getattr(jax.config, n) for n in names}
+        yield
+        for n, v in saved.items():
+            jax.config.update(n, v)
+
+    def test_key_holds_the_programs_scopes_and_checkout_relative_sources(self, monkeypatch, tmp_path):
+        import re
+
+        from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        enable_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        pattern = jax.config.jax_hlo_source_file_canonicalization_regex
+        assert re.sub(pattern, "", str(CHECKOUT / "src" / "repro" / "models" / "transformer.py")) == \
+            "src/repro/models/transformer.py"
+        assert re.sub(pattern, "", "/elsewhere/src/repro/models/transformer.py").startswith("/elsewhere")
+
     def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
         from repro.launch.compile_cache import enable_compile_cache
 
