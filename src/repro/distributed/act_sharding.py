@@ -16,6 +16,10 @@ Two jobs:
    reduce-scatter after — the standard SP schedule, visible in the
    dry-run's collective table.
 
+3. **Per-shard kernels** — a Pallas kernel is one custom call that GSPMD
+   cannot partition; :func:`map_batch_shards` runs it under ``shard_map``
+   on each device's rows of the batch (and heads, over ``model``).
+
 The step builders enter :func:`activation_sharding` around tracing; the
 model calls :func:`shard_activations` at the embedding and at every block
 boundary.  Outside any context the hook is a no-op, so single-device
@@ -26,9 +30,11 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Optional, Tuple, Union
+import math
+from typing import Callable, Optional, Tuple, Union
 
 import jax
+from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 Axes = Optional[Union[str, Tuple[str, ...]]]
@@ -48,10 +54,11 @@ def _axis_size(name: Axes) -> int:
 
 
 @contextlib.contextmanager
-def activation_sharding(batch_axes: Axes, seq_axes: Axes = None):
+def activation_sharding(batch_axes: Axes, seq_axes: Axes = None, mesh: Optional[Mesh] = None):
     """Declare mesh axes for the activation batch dim and (optionally) the
-    sequence dim of [B, S, D] activations."""
-    token = _SPEC.set((batch_axes, seq_axes))
+    sequence dim of [B, S, D] activations.  ``mesh`` is the step's mesh,
+    for :func:`map_batch_shards` where no ``shard_map`` already names it."""
+    token = _SPEC.set((batch_axes, seq_axes, mesh))
     try:
         yield
     finally:
@@ -63,7 +70,7 @@ def shard_activations(x):
     spec = _SPEC.get()
     if spec is None:
         return x
-    batch_axes, seq_axes = spec
+    batch_axes, seq_axes, _ = spec
     if x.ndim >= 3 and seq_axes is not None and x.shape[1] > 1:
         return jax.lax.with_sharding_constraint(
             x, P(batch_axes, seq_axes, *([None] * (x.ndim - 2)))
@@ -87,7 +94,7 @@ def shard_heads(x):
     spec = _SPEC.get()
     if spec is None or x.ndim < 3:
         return x
-    batch_axes, seq_axes = spec
+    batch_axes, seq_axes, _ = spec
     if seq_axes is None:
         return x
     if x.shape[2] % max(_axis_size(seq_axes), 1) != 0:
@@ -107,7 +114,56 @@ def replicate_seq(x):
     spec = _SPEC.get()
     if spec is None or x.ndim < 2:
         return x
-    batch_axes, _ = spec
+    batch_axes, _, _ = spec
     return jax.lax.with_sharding_constraint(
         x, P(batch_axes, *([None] * (x.ndim - 1)))
     )
+
+
+def map_batch_shards(fn: Callable, *xs):
+    """``fn(*xs)`` on each device's shard of ``xs`` ([B, S, H, ...] each).
+
+    For a Pallas kernel, which GSPMD cannot partition: under a mesh with an
+    automatic axis wider than one device, ``fn`` runs inside ``shard_map``,
+    manual over every such axis.  The batch is split over the active batch
+    axes and the heads over the sequence-parallel axis, each where it
+    divides evenly; every other axis holds whole copies.  ``fn`` must treat
+    batch rows and heads independently and return one array laid out like
+    ``xs[0]``.  Outside any context, or with no mesh to split over, it is
+    ``fn(*xs)``.
+    """
+    spec = _SPEC.get()
+    if spec is None:
+        return fn(*xs)
+    batch_axes, seq_axes, mesh = spec
+    ambient = jax.sharding.get_abstract_mesh()
+    mesh = mesh if ambient.empty else ambient
+    if mesh is None:
+        return fn(*xs)
+    sizes = dict(mesh.shape)
+    # a kernel lowers only where no axis is left to GSPMD, even of size one
+    manual = jax.sharding.AxisType.Manual
+    free = {n for n, t in zip(mesh.axis_names, mesh.axis_types) if t != manual}
+    if all(sizes[n] == 1 for n in free):
+        return fn(*xs)
+
+    def split(axes, dim):
+        axes = tuple(a for a in _names(axes) if a in free and sizes[a] > 1)
+        if any(x.shape[dim] % math.prod(sizes[a] for a in axes) for x in xs):
+            return None
+        return axes or None
+
+    dims = [None] * xs[0].ndim
+    dims[0] = split(batch_axes, 0)
+    dims[2] = split(seq_axes, 2)
+    pspec = P(*dims)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(pspec,) * len(xs), out_specs=pspec,
+        axis_names=free, check_vma=False,
+    )(*xs)
+
+
+def _names(axes: Axes) -> Tuple[str, ...]:
+    if axes is None:
+        return ()
+    return axes if isinstance(axes, tuple) else (axes,)

@@ -107,7 +107,7 @@ def make_train_step(
             "data" if "data" in mesh.axis_names else None
         )
         seq_axes = "model" if "model" in mesh.axis_names else None
-        with activation_sharding(act_axes, seq_axes):
+        with activation_sharding(act_axes, seq_axes, mesh):
             (loss, metrics), grads = jax.value_and_grad(
                 lambda p: loss_fn(p, batch, cfg), has_aux=True
             )(params)

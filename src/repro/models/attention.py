@@ -1,17 +1,33 @@
 """Grouped-query attention with full/sliding-window masks and KV caching.
 
-The jnp implementation here is the numerical reference and the path XLA
-compiles in dry-runs; on TPU the inner product is replaced by the Pallas
-flash-attention kernel (``repro.kernels.flash_attention``) when
-``use_flash=True`` — both paths are tested against each other.
+How attention is computed follows ``cfg.attn_impl``:
+
+* ``"auto"`` (the default).  :func:`attention_forward` is causal
+  self-attention over positions 0..S-1 by construction: training and
+  prefill call it on a whole sequence.  Compiled for a TPU, with S a
+  multiple of 128, it runs JAX's Pallas splash-attention kernel, forward
+  and backward (:func:`_sdpa_flash`); the kernel computes sliding windows
+  (``LocalMask``) and logit softcaps itself.  On any other platform, or
+  for any other S, it runs the jnp form that :func:`sdpa` picks: the
+  KV-block scan when S >= 2 * ``attn_block`` and divides into blocks, the
+  dense form otherwise.  Decode (one query against the cache) always runs
+  :func:`sdpa`.
+* ``"naive"``, ``"chunked"``, ``"chunked_kv"``: that jnp form everywhere.
+
+The jnp forms are the numerical reference; the kernel is tested against
+them in Pallas interpret mode.  ``jax.lax.platform_dependent`` makes the
+choice when the program is lowered, so a CPU run, and the dry-run on fake
+CPU devices, never see the kernel, and a compile for a described TPU does.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from .config import ModelConfig
 from .layers import apply_rope, dense_init, softcap
@@ -234,22 +250,103 @@ def sdpa(
     )
 
 
+def _flash_block(seq: int) -> int:
+    """The kernel's query and key block: the widest of 512, 256, 128 that divides ``seq``."""
+    return next(b for b in (512, 256, 128) if seq % b == 0)
+
+
+def _splash_kernel(seq: int, heads: int, window, logit_softcap, interpret: bool):
+    # built afresh in each trace (its mask tables are staged into the trace);
+    # splash caches the mask processing itself
+    if window is None:
+        mask = splash.CausalMask((seq, seq))
+    else:  # keys in (q - window, q], as sdpa's positional mask
+        mask = splash.LocalMask((seq, seq), window_size=(window - 1, 0), offset=0)
+    b = _flash_block(seq)
+    # seq-minor operands: the layout XLA gives q, k and v around the kernel
+    # (rotary halves, cache writes) without padding hd to 128 lanes
+    seq_minor = splash.QKVLayout.SEQ_MINOR
+    sizes = splash.BlockSizes(  # one backward kernel computes dq, dk and dv
+        block_q=b, block_kv=b, block_q_dkv=b, block_kv_dkv=b, use_fused_bwd_kernel=True,
+        q_layout=seq_minor, k_layout=seq_minor, v_layout=seq_minor,
+    )
+    return splash.make_splash_mha(
+        splash.MultiHeadMask([mask] * heads), block_sizes=sizes,
+        head_shards=1, q_seq_shards=1,
+        attn_logits_soft_cap=logit_softcap, interpret=interpret,
+    )
+
+
+def _sdpa_flash(q, k, v, *, window, logit_softcap, interpret: bool = False):
+    """Causal self-attention over positions 0..S-1 in JAX's Pallas splash
+    kernel, forward and backward: score tiles stay in VMEM, and blocks the
+    mask hides are skipped.  q: [B, S, H, hd]; k, v: [B, S, KVH, hd].
+
+    The kernel takes no scale, so q is scaled by ``hd ** -0.5`` in float32
+    before it.  Operands are in the compute dtype, with f32 accumulation and
+    f32 softmax statistics, as in the jnp forms, except under
+    differentiation, where q, and so the kernel's output, stay float32: the
+    backward takes each row's sum of ``o * do`` from that output, and a bf16
+    output leaves an error in rows of the score gradient that should sum to
+    zero, which the keys' gradient (a key bias under rotary positions)
+    collects.  Each device runs the kernel on its own rows and heads
+    (``map_batch_shards``).
+    """
+    from repro.distributed.act_sharding import map_batch_shards
+
+    def local(q, k, v):
+        heads_first = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
+
+        def attend(q_dtype):
+            def run(q, k, v):
+                kernel = _splash_kernel(q.shape[1], q.shape[2], window, logit_softcap, interpret)
+                q = (q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(q_dtype)
+                out = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
+                return heads_first(out).astype(k.dtype)
+            return run
+
+        rounded, exact = attend(k.dtype), attend(jnp.float32)
+        fn = jax.custom_vjp(rounded)
+        fn.defvjp(lambda q, k, v: jax.vjp(exact, q, k, v), lambda vjp, do: vjp(do))
+        return fn(q, k, v)
+
+    with jax.named_scope("flash"):
+        return map_batch_shards(local, q, k, v)
+
+
+def causal_self_attention(q, k, v, cfg: ModelConfig, *, window: Optional[int]):
+    """Attention of positions 0..S-1 to themselves (module docstring)."""
+    positions = jnp.arange(q.shape[1])
+
+    def jnp_form(q, k, v):
+        return sdpa(
+            q, k, v,
+            q_positions=positions, k_positions=positions,
+            window=window, logit_softcap=cfg.attn_logit_softcap,
+            impl=cfg.attn_impl, block=cfg.attn_block,
+        )
+
+    if cfg.attn_impl != "auto" or q.shape[1] % 128:
+        return jnp_form(q, k, v)
+    kernel = functools.partial(
+        _sdpa_flash, window=window, logit_softcap=cfg.attn_logit_softcap
+    )
+    return jax.lax.platform_dependent(q, k, v, tpu=kernel, default=jnp_form)
+
+
 def attention_forward(
     params: Params,
-    x,  # [B, S, D]
+    x,  # [B, S, D]: positions 0..S-1
     cfg: ModelConfig,
     *,
     window: Optional[int],
-    positions=None,  # [S] absolute positions, defaults to arange
     return_cache: bool = False,
     cache_len: Optional[int] = None,  # total decode capacity (>= S)
 ):
     """Training / prefill attention; optionally returns the KV cache."""
     from repro.distributed.act_sharding import replicate_seq
 
-    B, S, _ = x.shape
-    if positions is None:
-        positions = jnp.arange(S)
+    positions = jnp.arange(x.shape[1])
     q, k, v = _project_qkv(params, x, cfg)
     # sequence-parallel: q stays seq-sharded through the KV-block scan;
     # only k/v (2*kv_heads*head_dim wide, vs d_model for activations) are
@@ -258,18 +355,13 @@ def attention_forward(
     k, v = replicate_seq(k), replicate_seq(v)
     q = apply_rope(q, positions, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
     k = apply_rope(k, positions, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
-    out = sdpa(
-        q, k, v,
-        q_positions=positions, k_positions=positions,
-        window=window, logit_softcap=cfg.attn_logit_softcap,
-        impl=cfg.attn_impl, block=cfg.attn_block,
-    )
+    out = causal_self_attention(q, k, v, cfg, window=window)
     y = _out_proj(params, out, cfg)
     if not return_cache:
         return y, None
     with jax.named_scope("kv_cache"):
         cache = make_cache_from_prefill(
-            k, v, positions, window=window, max_len=cache_len or S
+            k, v, positions, window=window, max_len=cache_len or x.shape[1]
         )
     return y, cache
 

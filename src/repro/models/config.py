@@ -58,10 +58,13 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     rope_fraction: float = 1.0  # chatglm applies rotary to half the head dim
     attn_logit_softcap: Optional[float] = None
-    # attention execution: "naive" materializes (Sq, Sk) logits; "chunked"
-    # processes query blocks sequentially (flash-style memory, O(block*Sk));
-    # "auto" chunks when Sq >= 2*attn_block.  On TPU the Pallas flash kernel
-    # replaces both (kernels/flash_attention).
+    # attention execution (models/attention.py): "auto" runs training and
+    # prefill attention in JAX's Pallas splash kernel when compiled for a TPU
+    # with a sequence a multiple of 128, else (and always in decode) the jnp
+    # form below that fits: "chunked_kv" (KV-block scan, online softmax) when
+    # Sq >= 2*attn_block and both lengths divide into blocks, else "naive"
+    # (materialized (Sq, Sk) logits).  "naive", "chunked" (query-block scan,
+    # O(block*Sk) memory) and "chunked_kv" force that jnp form everywhere.
     attn_impl: str = "auto"
     attn_block: int = 512
 

@@ -112,14 +112,14 @@ def _layer_window(kind: str, cfg: ModelConfig) -> Optional[int]:
     return None
 
 
-def _block_train(params: Params, x, kind: str, cfg: ModelConfig, positions):
+def _block_train(params: Params, x, kind: str, cfg: ModelConfig):
     """One layer (training/prefill, no cache). Returns (x, aux, cache)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in (ATTN, LOCAL):
         with jax.named_scope("attention"):
             h = apply_norm(params["norm1"], x, cfg)
             attn_out, _ = attention_forward(
-                params["attn"], h, cfg, window=_layer_window(kind, cfg), positions=positions
+                params["attn"], h, cfg, window=_layer_window(kind, cfg)
             )
         x = x + attn_out
         with jax.named_scope("ffn"):
@@ -154,15 +154,14 @@ def _block_train(params: Params, x, kind: str, cfg: ModelConfig, positions):
     return x, aux
 
 
-def _block_prefill(params: Params, x, kind: str, cfg: ModelConfig, positions, max_len: int):
+def _block_prefill(params: Params, x, kind: str, cfg: ModelConfig, max_len: int):
     """One layer, returning its decode cache."""
     if kind in (ATTN, LOCAL):
         with jax.named_scope("attention"):
             h = apply_norm(params["norm1"], x, cfg)
             attn_out, cache = attention_forward(
                 params["attn"], h, cfg,
-                window=_layer_window(kind, cfg), positions=positions,
-                return_cache=True, cache_len=max_len,
+                window=_layer_window(kind, cfg), return_cache=True, cache_len=max_len,
             )
         x = x + attn_out
         with jax.named_scope("ffn"):
@@ -241,7 +240,7 @@ def _block_decode(params: Params, x_t, cache, kind: str, cfg: ModelConfig, posit
 
 
 def embed_inputs(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig):
-    """Token + stub-frontend embedding -> (x [B, S, D], positions [S])."""
+    """Token + stub-frontend embedding -> x [B, S, D], for positions 0..S-1."""
     from repro.distributed.act_sharding import shard_activations
 
     dt = cfg.compute_dtype
@@ -254,8 +253,7 @@ def embed_inputs(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig
                 patches = batch["patch_embeds"].astype(dt) @ params["frontend_proj"].astype(dt)
                 x = jnp.concatenate([patches, x], axis=1)
         x = shard_activations(x)  # batch dim -> ("pod",)"data" per active context
-        positions = jnp.arange(x.shape[1])
-    return x, positions
+    return x
 
 
 def unembed(params: Params, x, cfg: ModelConfig):
@@ -284,14 +282,14 @@ def _maybe_remat(fn, cfg: ModelConfig):
 
 def forward(params: Params, batch, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full forward pass -> (logits [B, S, V], moe_aux scalar)."""
-    x, positions = embed_inputs(params, batch, cfg)
+    x = embed_inputs(params, batch, cfg)
 
     from repro.distributed.act_sharding import shard_activations
 
     def group_body(carry, slot_params):
         x, aux = carry
         for s, kind in enumerate(cfg.pattern):
-            x, a = _block_train(slot_params[f"slot{s}"], x, kind, cfg, positions)
+            x, a = _block_train(slot_params[f"slot{s}"], x, kind, cfg)
             aux = aux + a
         # sequence-parallel boundary: the scan carry (= remat residual)
         # lives sharded over (batch, seq) between blocks.
@@ -310,7 +308,7 @@ def forward(params: Params, batch, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.n
                     slot_i = jax.tree.map(lambda a, i=i: a[i], params["groups"])
                     (x, aux), _ = body((x, aux), slot_i)
         for i, kind in enumerate(cfg.remainder):
-            x, a = _block_train(params["remainder"][i], x, kind, cfg, positions)
+            x, a = _block_train(params["remainder"][i], x, kind, cfg)
             aux = aux + a
     logits = unembed(params, x, cfg)
     return logits, aux
@@ -343,7 +341,7 @@ def loss_fn(params: Params, batch, cfg: ModelConfig):
 def prefill(params: Params, batch, cfg: ModelConfig, *, max_len: Optional[int] = None):
     """Forward + caches. Returns (last-position logits [B, V], cache pytree)."""
     with jax.named_scope("prefill"):
-        x, positions = embed_inputs(params, batch, cfg)
+        x = embed_inputs(params, batch, cfg)
         max_len = max_len or x.shape[1]
 
         from repro.distributed.act_sharding import shard_activations
@@ -351,9 +349,7 @@ def prefill(params: Params, batch, cfg: ModelConfig, *, max_len: Optional[int] =
         def group_body(x, slot_params):
             caches = {}
             for s, kind in enumerate(cfg.pattern):
-                x, cache = _block_prefill(
-                    slot_params[f"slot{s}"], x, kind, cfg, positions, max_len
-                )
+                x, cache = _block_prefill(slot_params[f"slot{s}"], x, kind, cfg, max_len)
                 caches[f"slot{s}"] = cache
             return shard_activations(x), caches
 
@@ -371,7 +367,7 @@ def prefill(params: Params, batch, cfg: ModelConfig, *, max_len: Optional[int] =
                     cache["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
             rem = []
             for i, kind in enumerate(cfg.remainder):
-                x, c = _block_prefill(params["remainder"][i], x, kind, cfg, positions, max_len)
+                x, c = _block_prefill(params["remainder"][i], x, kind, cfg, max_len)
                 rem.append(c)
         if rem:
             cache["remainder"] = rem

@@ -7,7 +7,9 @@ here lowers and compiles for ``v5e:2x2`` devices described by
 the Pallas kernels at real widths (a ``tpu_custom_call`` in the HLO) —
 the WAN quantizers also at the widest trailing dimension of any config's
 parameter leaf, where a slab must still fit VMEM — the full-width distilgpt2-82m train step on one chip (fits its 16 GB), and a
-2-pod ``hier_int8`` step on the four-chip host layout (2, 2, 1).
+2-pod ``hier_int8`` step on the four-chip host layout (2, 2, 1).  Each
+step program, and the serving prefill, must hold the Pallas flash-attention
+kernel under the ``attention`` scope (``models/attention.py``).
 
 Nothing runs, so these say nothing about results or speed.  The topology
 is described inside a module fixture, never at import: only one process
@@ -16,7 +18,9 @@ every test file.  The persistent compilation cache is off around these
 compiles (an executable for a described chip cannot be read back).
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +33,7 @@ from repro.kernels.rwkv6_wkv import wkv6_fwd
 from repro.kernels.wan_quant import dequantize, quantize
 from repro.launch.mesh import make_mesh
 from repro.launch.shapes import params_specs
+from repro.models import prefill
 from repro.optim import AdamWConfig
 
 #: device memory of one TPU v5e chip (Google Cloud documentation, "TPU v5e")
@@ -73,6 +78,16 @@ def _peak_bytes(compiled) -> int:
         mem.argument_size_in_bytes + mem.temp_size_in_bytes
         + mem.output_size_in_bytes - mem.alias_size_in_bytes
     )
+
+
+def _attention_kernels(compiled):
+    """Op names of the compiled program's Pallas kernels that lie under
+    ``attention``, in the ``flash`` scope."""
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call".*?, metadata=\{op_name="([^"]+)"',
+        compiled.as_text(), re.S,
+    )
+    return [n for n in names if {"attention", "flash"} <= set(n.split("/"))]
 
 
 def _widest_leaf():
@@ -151,6 +166,28 @@ def test_full_width_train_step_fits_one_chip(topo):
     mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
     compiled = _lower_train_step(get_config("distilgpt2-82m"), mesh, "hier").compile()
     assert 0 < _peak_bytes(compiled) < V5E_HBM_BYTES
+    assert _attention_kernels(compiled)
+
+
+@pytest.mark.parametrize("program", ["olmo-1b-l4.train.b4x2048", "distilgpt2-82m.prefill.b128x512"])
+def test_attention_kernel_in_program(topo, one_chip, program):
+    """The benchmark's other two programs on one v5e chip: OLMo-1B's first
+    4 layers (remat full) trained at 4 x 2048, and distilgpt2-82m's prefill
+    of 128 prompts of 512 tokens into a 576-position cache."""
+    if program.startswith("olmo"):
+        mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+        cfg = dataclasses.replace(get_config("olmo-1b"), num_layers=4)
+        compiled = _lower_train_step(cfg, mesh, "hier", seq_len=2048, global_batch=4).compile()
+    else:
+        cfg = get_config("distilgpt2-82m")
+        params = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+            params_specs(cfg),
+        )
+        batch = {"tokens": jax.ShapeDtypeStruct((128, 512), jnp.int32, sharding=one_chip)}
+        compiled = _compile(lambda p, b: prefill(p, b, cfg, max_len=576), params, batch)
+    assert 0 < _peak_bytes(compiled) < V5E_HBM_BYTES
+    assert _attention_kernels(compiled)
 
 
 def test_two_pod_hier_int8_step_compiles(topo):
@@ -160,3 +197,4 @@ def test_two_pod_hier_int8_step_compiles(topo):
     hlo = compiled.as_text()
     assert "all-gather" in hlo  # the int8 payload and its scales cross the pods
     assert 0 < _peak_bytes(compiled) < V5E_HBM_BYTES
+    assert _attention_kernels(compiled)  # per device, under shard_map over "data"
