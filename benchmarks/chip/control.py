@@ -2,8 +2,10 @@
 
     python3 benchmarks/chip/control.py --workload <cell> --seeds 1,2,3
 
-The benchmark's own runs do not run this.  One process, one line of JSON
-per seed.  Training cells: the numbers of ``checks.train_numbers`` for
+The benchmark's own runs do not run this.  One process on one chip, one
+line of JSON per seed; the reference is the configuration's own
+(``reference.lookup``).  Training cells: the numbers of
+``checks.train_numbers`` for
 
 * ``control``: the reference put in the program's place and computed with
   float8 (e4m3, per-tensor scale) matmul operands, the step below the
@@ -45,13 +47,14 @@ def train_readings(spec, seed: int):
     from benchmarks.chip.drive_train import model_config, weights_fn
 
     t, m = spec["traffic"], spec["config"]["model"]
+    ref_of = reference.lookup(spec["cell"]["config"])
     cfg = model_config(spec["config"])
     pods, checked = t.get("pods", 1), t["checked_steps"]
     make = weights_fn(cfg)
     batches = inputs.train_pool(seed, cfg.vocab_size, t["pool_batches"], t["global_batch"], t["seq_len"])[:checked]
 
     def follow(batches, precision="f32", pods_run=pods, strategy=t["strategy"]):
-        losses, grad, params = reference.train_steps(
+        losses, grad, params = ref_of.train_steps(
             make(seed), batches, m, t["adamw"], strategy=strategy, pods=pods_run,
             precision=precision, rows_per_block=t["ref_rows_per_block"])
         return {"losses": losses, "grad": reference.to_host(grad),
@@ -67,15 +70,16 @@ def train_readings(spec, seed: int):
         # pod 0 alone: its own rows, its own (compressed) gradient, no exchange
         pod0 = [{k: v[:rows] for k, v in b.items()} for b in batches]
         if t["strategy"] == "hier_int8":
-            readings = follow_int8_alone(make(seed), pod0, m, t)
+            readings = follow_int8_alone(ref_of, make(seed), pod0, m, t)
         else:
             readings = follow(pod0, pods_run=1)
         out["no_exchange"] = train_numbers(readings, ref)
     return out
 
 
-def follow_int8_alone(params, batches, m, t):
-    """One pod that compresses its gradient with error feedback and keeps it."""
+def follow_int8_alone(ref_of, params, batches, m, t):
+    """One pod that compresses its gradient with error feedback and keeps it
+    (``ref_of``: the configuration's reference)."""
     import jax
 
     from benchmarks.chip import reference
@@ -85,7 +89,7 @@ def follow_int8_alone(params, batches, m, t):
     params0 = jax.tree.map(lambda a: a.copy(), params)
     losses, first = [], None
     for k, b in enumerate(batches, start=1):
-        loss, g = reference.loss_and_grad(params, b["tokens"], b["labels"], m, "f32", t["ref_rows_per_block"])
+        loss, g = ref_of.loss_and_grad(params, b["tokens"], b["labels"], m, "f32", t["ref_rows_per_block"])
         losses.append(loss)
         boosted = reference.tree_add(g, ef)
         sent = reference.round_trip_tree(boosted)
@@ -104,6 +108,7 @@ def serve_readings(spec, seed: int, seconds: float):
     from benchmarks.chip.drive_train import model_config, weights_fn
 
     t, m = spec["traffic"], spec["config"]["model"]
+    ref_of = reference.lookup(spec["cell"]["config"])
     cfg = model_config(spec["config"])
     B, P, rows = t["batch"], t["prompt_len"], t["ref_rows_per_block"]
     params = weights_fn(cfg)(seed)
@@ -111,10 +116,10 @@ def serve_readings(spec, seed: int, seconds: float):
     server.batch(inputs.serve_batch(seed, "warmup", 0, B, P, cfg.vocab_size))
     loop = closed_loop(server, lambda b: inputs.serve_batch(seed, "window", b, B, P, cfg.vocab_size), seconds)
     prompts, served = sample(seed, loop, t["sample_requests"])
-    gaps, ctrl = reference.served_gaps(params, prompts, served, m, precision="fp8", rows_per_block=rows)
+    gaps, ctrl = ref_of.served_gaps(params, prompts, served, m, precision="fp8", rows_per_block=rows)
     altered = served.copy()
     altered[0, t["gen_tokens"] // 2] = (altered[0, t["gen_tokens"] // 2] + 1) % cfg.vocab_size
-    alt, _ = reference.served_gaps(params, prompts, altered, m, rows_per_block=rows)
+    alt, _ = ref_of.served_gaps(params, prompts, altered, m, rows_per_block=rows)
 
     # the program with ``decode_step`` returning the cache it was given, on the
     # same batch as the sampled requests' first
@@ -122,7 +127,7 @@ def serve_readings(spec, seed: int, seconds: float):
     program_decode = stuck.decode
     stuck.decode = jax.jit(lambda p, tok, cache, pos: (program_decode(p, tok, cache, pos)[0], cache))
     frozen = stuck.batch(loop["prompts"][:B])[0][: t["sample_requests"]]
-    cache_gaps, _ = reference.served_gaps(params, loop["prompts"][: len(frozen)], frozen, m, rows_per_block=rows)
+    cache_gaps, _ = ref_of.served_gaps(params, loop["prompts"][: len(frozen)], frozen, m, rows_per_block=rows)
     return {"program": float(gaps.max()), "control": float(ctrl.max()),
             "token_altered": float(alt.max()), "cache_unchanged": float(cache_gaps.max()),
             "batches": len(loop["ttft"])}

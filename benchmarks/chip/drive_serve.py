@@ -129,8 +129,9 @@ def run(spec: Dict, devs, *, seed: int, seconds: float, tracer, t_process: float
     # -- the reference, over a sample of the served requests ----------------------
     t_ref = time.perf_counter()
     prompts, served = sample(seed, loop, t["sample_requests"])
-    gaps, _ = reference.served_gaps(make_params(seed), prompts, served, spec["config"]["model"],
-                                    rows_per_block=t["ref_rows_per_block"])
+    ref_of = reference.lookup(spec["cell"]["config"])
+    gaps, _ = ref_of.served_gaps(make_params(seed), prompts, served, spec["config"]["model"],
+                                 rows_per_block=t["ref_rows_per_block"])
     numbers = {"token_gap": float(gaps.max())}
     info = {
         "setup_compiles": f"{setup_compiles.hits} hits / {setup_compiles.misses} misses, "
@@ -140,6 +141,7 @@ def run(spec: Dict, devs, *, seed: int, seconds: float, tracer, t_process: float
         "ttft_ms_max": f"{loop['ttft'].max() * 1e3:.3f}",
         "itl_ms_max": f"{loop['itl'].max() * 1e3:.3f}",
         "checked_tokens": int(gaps.size),
+        "reference": ref_of.source,
         "reference_s": f"{time.perf_counter() - t_ref:.2f}",
     }
     log(f"window {batches * B} requests in {batches} batches, {loop['seconds']:.3f}s; set-up {setup_s:.2f}s")

@@ -177,7 +177,8 @@ def run(spec: Dict, devs, *, seed: int, seconds: float, tracer, t_process: float
     # -- the reference, after the window and with the program's state freed ---
     t_ref = time.perf_counter()
     ref_weights = weights_fn(cfg)
-    ref_losses, ref_grad, ref_params = reference.train_steps(
+    ref_of = reference.lookup(spec["cell"]["config"])
+    ref_losses, ref_grad, ref_params = ref_of.train_steps(
         ref_weights(seed), pool[:checked], spec["config"]["model"], t["adamw"],
         strategy=t["strategy"], pods=pods, precision="f32", rows_per_block=t["ref_rows_per_block"],
         devices=devs,
@@ -193,6 +194,7 @@ def run(spec: Dict, devs, *, seed: int, seconds: float, tracer, t_process: float
                           f"{setup_compiles.compile_s:.2f}s",
         "window_compiles": compiles.hits + compiles.misses,
         "losses": [f"{a:.7f}/{b:.7f}" for a, b in zip(program["losses"], ref["losses"])],
+        "reference": ref_of.source,
         "reference_s": f"{time.perf_counter() - t_ref:.2f}",
     }
     log(f"window {steps} steps in {window_s:.3f}s; set-up {clock['start'] - t_process:.2f}s")

@@ -8,10 +8,13 @@ traffic mix.  The harness finds
   module that runs it (``drive_train`` or ``drive_serve``);
 * the limits of the output comparison at ``limits/<cell>.json``;
 * each per-layer metric's reader at ``metrics/<metric>.py``, a module with
-  ``read(rec) -> float | None``.
+  ``read(rec) -> float | None``;
+* the plain reference's architecture at ``references/<config>.py``, a
+  module with ``forward`` (``reference.lookup``), or the dense decoder of
+  ``dense.py`` where the configuration brings none.
 
-So a later change adds a cell, a traffic mix or a metric by adding files
-and entries, never by editing these.
+So a later change adds a cell, a traffic mix, a metric or an architecture
+by adding files and entries, never by editing these.
 """
 
 from __future__ import annotations

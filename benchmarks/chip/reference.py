@@ -1,37 +1,51 @@
-"""Plain reference: the decoder, its loss, AdamW and the int8 pod exchange.
+"""Plain reference: what every architecture shares, and the lookup of the
+architecture a configuration runs.
 
-Written from the configuration file alone, in float32 with every matmul at
-``Precision.HIGHEST``; it imports nothing of the program.  It reads the
-weights in the layout the benchmark made them (``inputs.weights_builder``):
-``embed [V, D]``, ``final_norm``, and per-layer leaves stacked on a
-leading layer axis under ``groups/slot0``.
+A configuration's architecture is one function,
 
-The architecture, as the configurations state it:
+    forward(params, tokens, m, precision) -> (logits, extra_loss)
 
-* pre-norm decoder blocks; LayerNorm (eps 1e-5) with scale and bias, or
-  OLMo's non-parametric LayerNorm;
-* rotary positions over each head (theta 10000, the two halves of the
-  head rotated against each other), scale ``head_dim ** -0.5``, causal;
-* GELU (tanh form, GPT-2's ``gelu_new``) or SwiGLU feed-forward;
-* tied unembedding; loss = mean next-token cross-entropy
-  + ``z_loss * mean(logsumexp(logits) ** 2)``.
+from ``[B, S]`` tokens to ``[B, S, V]`` float32 logits, written from the
+configuration file's ``model`` block ``m`` alone and importing nothing of
+the program.  ``extra_loss`` is the architecture's own term of the loss for
+these rows (a mixture of experts' balance loss, with its coefficient), a
+mean over their tokens, or ``0.0``.  ``lookup(config)`` takes it from
+``references/<config name>.py`` where that file exists, and otherwise from
+``dense.py``, the dense tied decoder.  Each reads the weights in the layout
+the benchmark made them (``inputs.weights_builder``).
 
-``precision="fp8"`` is the control: every matmul operand of the forward
-pass is rounded to float8 e4m3 with a per-tensor absmax scale before an f32
-product; gradients flow through the rounding unchanged.
+Shared by every architecture, here:
+
+* the precision helpers: every matmul through ``einsum``, in float32 at
+  ``Precision.HIGHEST``; ``precision="fp8"`` is the control, which rounds
+  every matmul operand of the forward pass to float8 e4m3 with a
+  per-tensor absmax scale before an f32 product (gradients flow through
+  the rounding unchanged);
+* the loss: mean next-token cross-entropy
+  + ``z_loss * mean(logsumexp(logits) ** 2)`` + the extra term, and its
+  gradient, accumulated over blocks of rows (an extra term is weighted by
+  its block's share of the tokens);
+* the pod exchange of each strategy, with the int8 round trip and error
+  feedback of ``hier_int8``; AdamW; per-leaf norms and changes;
+* ``served_gaps``, which compares served tokens with the forward pass.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
-import math
-from typing import Dict, List, Mapping, Sequence, Tuple
+from pathlib import Path
+from typing import Callable, Dict, List, Mapping, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .inputs import leaf_name
+
+#: Where a configuration's own architecture lives, as ``<config name>.py``.
+REFERENCES = Path(__file__).resolve().parent / "references"
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -48,130 +62,148 @@ def _round(x, precision: str):
     return x + jax.lax.stop_gradient(low - x)
 
 
-def _einsum(spec: str, a, b, precision: str):
+def einsum(spec: str, a, b, precision: str):
     return jnp.einsum(spec, _round(a, precision), _round(b, precision), precision=HIGHEST)
 
 
-def _norm(p: Mapping, x, m: Mapping):
-    mean = x.mean(-1, keepdims=True)
-    var = jnp.square(x - mean).mean(-1, keepdims=True)
-    y = (x - mean) / jnp.sqrt(var + 1e-5)
-    if m["norm"] == "layernorm":
-        y = y * p["scale"] + p["bias"]
-    elif m["norm"] != "nonparametric_ln":
-        raise ValueError(f"reference has no norm {m['norm']!r}")
-    return y
+# -- the architecture ------------------------------------------------------------------
+
+Forward = Callable[[Mapping, jnp.ndarray, Mapping, str], Tuple[jnp.ndarray, jnp.ndarray]]
 
 
-def _rope(x, theta: float):
-    """x: [B, S, H, hd]; the first and second halves of hd rotate together."""
-    hd = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    a, b = x[..., : hd // 2], x[..., hd // 2 :]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+@functools.lru_cache(maxsize=None)
+def _forward_at(path: str) -> Forward:
+    """``forward`` of the module at ``path``, loaded once per process."""
+    spec = importlib.util.spec_from_file_location("bench_reference_" + Path(path).stem.replace(".", "_"), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.forward
 
 
-def _gelu_tanh(x):
-    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi) * (x + 0.044715 * x**3)))
+def lookup(config: str) -> "Reference":
+    """The reference of configuration ``config``: its own architecture in
+    ``references/<config>.py`` where that file exists, else the dense decoder."""
+    path = REFERENCES / f"{config}.py"
+    if path.is_file():
+        return Reference(_forward_at(str(path)), str(path))
+    from . import dense
 
-
-def _layer(p: Mapping, x, m: Mapping, precision: str):
-    B, S, D = x.shape
-    H, KVH = m["num_heads"], m["num_kv_heads"]
-    hd = m.get("head_dim") or D // H
-    bias = m.get("use_bias_attn", False)
-    h = _norm(p.get("norm1", {}), x, m)
-    a = p["attn"]
-    q = _einsum("bsd,de->bse", h, a["wq"], precision) + (a["bq"] if bias else 0.0)
-    k = _einsum("bsd,de->bse", h, a["wk"], precision) + (a["bk"] if bias else 0.0)
-    v = _einsum("bsd,de->bse", h, a["wv"], precision) + (a["bv"] if bias else 0.0)
-    q = _rope(q.reshape(B, S, H, hd), m["rope_theta"])
-    k = _rope(k.reshape(B, S, KVH, hd), m["rope_theta"])
-    v = v.reshape(B, S, KVH, hd)
-    rep = H // KVH
-    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-    s = _einsum("bqhd,bkhd->bhqk", q, k, precision) / math.sqrt(hd)
-    pos = jnp.arange(S)
-    s = jnp.where((pos[:, None] >= pos[None, :])[None, None], s, -jnp.inf)
-    probs = jax.nn.softmax(s, axis=-1)
-    o = _einsum("bhqk,bkhd->bqhd", probs, v, precision).reshape(B, S, H * hd)
-    x = x + _einsum("bse,ed->bsd", o, a["wo"], precision) + (a["bo"] if bias else 0.0)
-    h = _norm(p.get("norm2", {}), x, m)
-    f = p["ffn"]
-    if m["activation"] == "gelu":
-        u = _einsum("bsd,df->bsf", h, f["w_up"], precision)
-        if m.get("use_bias_mlp"):
-            u = u + f["b_up"]
-        u = _gelu_tanh(u)
-    elif m["activation"] == "swiglu":
-        g = _einsum("bsd,df->bsf", h, f["w_gate"], precision)
-        u = jax.nn.sigmoid(g) * g * _einsum("bsd,df->bsf", h, f["w_up"], precision)
-    else:
-        raise ValueError(f"reference has no activation {m['activation']!r}")
-    y = _einsum("bsf,fd->bsd", u, f["w_down"], precision)
-    if m.get("use_bias_mlp"):
-        y = y + f["b_down"]
-    return x + y
-
-
-def logits(params: Mapping, tokens, m: Mapping, precision: str = "f32"):
-    """[B, S] tokens -> [B, S, V] float32 logits."""
-    if tuple(m["pattern"]) != ("attn",) or not m.get("tie_embeddings") or m.get("moe"):
-        raise ValueError("the reference covers dense, tied, attention-only decoders")
-    x = params["embed"].astype(jnp.float32)[tokens]
-    layers = jax.tree.map(lambda a: a.astype(jnp.float32), params["groups"]["slot0"])
-    layer = jax.checkpoint(lambda x, p: (_layer(p, x, m, precision), None))
-    x, _ = jax.lax.scan(layer, x, layers)
-    h = _norm(params.get("final_norm", {}), x, m)
-    return _einsum("bsd,vd->bsv", h, params["embed"].astype(jnp.float32), precision)
+    return Reference(dense.forward, "dense.py")
 
 
 # -- loss and gradient, in blocks of rows -------------------------------------------
 
-
-def _block_terms(params, tokens, labels, m: Mapping, precision: str):
-    lg = logits(params, tokens, m, precision)
-    logz = jax.nn.logsumexp(lg, axis=-1)
-    ll = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0] - logz
-    return -ll.sum(), jnp.square(logz).sum()
+_GRAD_FNS: Dict[Tuple[Forward, str], object] = {}
 
 
-_GRAD_FNS: Dict[str, object] = {}
-
-
-def _block_grad(m: Mapping, precision: str, n_tokens: int):
-    key = json.dumps([m, precision, n_tokens], sort_keys=True)
+def _block_grad(forward: Forward, m: Mapping, precision: str, n_tokens: int):
+    key = (forward, json.dumps([m, precision, n_tokens], sort_keys=True))
     if key not in _GRAD_FNS:
         def objective(params, tokens, labels):
-            ce, zz = _block_terms(params, tokens, labels, m, precision)
-            return ce / n_tokens + m["z_loss"] * zz / n_tokens, (ce, zz)
+            lg, extra = forward(params, tokens, m, precision)
+            logz = jax.nn.logsumexp(lg, axis=-1)
+            ll = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0] - logz
+            ce, zz = -ll.sum(), jnp.square(logz).sum()
+            extra = extra * (tokens.size / n_tokens)
+            return ce / n_tokens + m["z_loss"] * zz / n_tokens + extra, (ce, zz, extra)
 
         _GRAD_FNS[key] = jax.jit(jax.grad(objective, has_aux=True))
     return _GRAD_FNS[key]
 
 
-def loss_and_grad(params, tokens: np.ndarray, labels: np.ndarray, m: Mapping,
-                  precision: str, rows_per_block: int, devices: Sequence = ()):
-    """Mean loss and its gradient over all rows, accumulated block by block;
-    with several ``devices``, each block holds ``rows_per_block`` rows per device."""
-    n_tokens = tokens.size
-    step = _block_grad(m, precision, n_tokens)
-    width, put = rows_per_block, lambda a: a
-    if len(devices) > 1:
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+class Reference:
+    """The shared loss, training steps and served-token comparison, over one
+    architecture's ``forward``; ``source`` says where that came from."""
 
-        mesh = Mesh(np.asarray(devices), ("rows",))
-        params = jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
-        width *= len(devices)
-        put = lambda a: jax.device_put(a, NamedSharding(mesh, PartitionSpec("rows")))  # noqa: E731
-    grads, ce, zz = None, 0.0, 0.0
-    for r in range(0, tokens.shape[0], width):
-        g, (c, z) = step(params, put(tokens[r : r + width]), put(labels[r : r + width]))
-        grads = g if grads is None else tree_add(grads, g)
-        ce, zz = ce + float(c), zz + float(z)
-    return ce / n_tokens + m["z_loss"] * zz / n_tokens, grads
+    def __init__(self, forward: Forward, source: str):
+        self.forward, self.source = forward, source
+
+    def loss_and_grad(self, params, tokens: np.ndarray, labels: np.ndarray, m: Mapping,
+                      precision: str, rows_per_block: int, devices: Sequence = ()):
+        """Mean loss and its gradient over all rows, accumulated block by block;
+        with several ``devices``, each block holds ``rows_per_block`` rows per device."""
+        n_tokens = tokens.size
+        step = _block_grad(self.forward, m, precision, n_tokens)
+        width, put = rows_per_block, lambda a: a
+        if len(devices) > 1:
+            from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+            mesh = Mesh(np.asarray(devices), ("rows",))
+            params = jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
+            width *= len(devices)
+            put = lambda a: jax.device_put(a, NamedSharding(mesh, PartitionSpec("rows")))  # noqa: E731
+        grads, ce, zz, extra = None, 0.0, 0.0, 0.0
+        for r in range(0, tokens.shape[0], width):
+            g, (c, z, x) = step(params, put(tokens[r : r + width]), put(labels[r : r + width]))
+            grads = g if grads is None else tree_add(grads, g)
+            ce, zz, extra = ce + float(c), zz + float(z), extra + float(x)
+        return ce / n_tokens + m["z_loss"] * zz / n_tokens + extra, grads
+
+    def train_steps(self, params, batches: Sequence[Mapping[str, np.ndarray]], m: Mapping, opt: Mapping, *,
+                    strategy: str, pods: int, precision: str, rows_per_block: int, devices: Sequence = ()):
+        """Follow the first ``len(batches)`` steps.  Returns per-step losses,
+        the per-leaf norms of the first step's clipped gradient and the final
+        parameters.  Buffers are donated from step to step, so that the
+        reference holds one copy of the parameters, each moment and the
+        gradient."""
+        params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+        zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
+        mom, vel = zeros(params), zeros(params)
+        ef = [zeros(params) for _ in range(pods)]
+        losses: List[float] = []
+        first_grad = None
+        for k, batch in enumerate(batches, start=1):
+            rows = batch["tokens"].shape[0] // pods
+            pod_loss, pod_grads = [], []
+            for p in range(pods):
+                sl = slice(p * rows, (p + 1) * rows)
+                loss, g = self.loss_and_grad(params, batch["tokens"][sl], batch["labels"][sl], m,
+                                             precision, rows_per_block, devices)
+                pod_loss.append(loss)
+                pod_grads.append(g)
+            losses.append(sum(pod_loss) / pods)
+            if strategy == "hier_int8" and pods > 1:
+                boosted = [tree_add(g, e) for g, e in zip(pod_grads, ef)]
+                sent = [round_trip_tree(b) for b in boosted]
+                ef = [tree_sub(b, s) for b, s in zip(boosted, sent)]
+                grads = tree_mean(sent)
+            else:
+                grads = tree_mean(pod_grads)
+            params, mom, vel, clipped = adamw(opt, grads, mom, vel, params, k)
+            if first_grad is None:
+                first_grad = leaf_norms(clipped)
+            del clipped
+        return losses, first_grad, params
+
+    def served_gaps(self, params, prompts: np.ndarray, served: np.ndarray, m: Mapping, *,
+                    precision: str = "f32", rows_per_block: int = 1) -> Tuple[np.ndarray, np.ndarray]:
+        """For each served token, how far its reference logit lies below the
+        reference's best at that position.
+
+        prompts [R, P], served [R, G].  Returns ``(gap, control_gap)``, each [R, G]:
+        the gap of the served token, and (for ``precision != "f32"``) the gap of
+        the token the lower precision puts first at the same position.
+        """
+        P = prompts.shape[1]
+        seq = np.concatenate([prompts, served[:, :-1]], axis=1)
+
+        @jax.jit
+        def block(params, toks, picks):
+            ref = self.forward(params, toks, m, "f32")[0][:, P - 1 :]
+            best = ref.max(-1)
+            gap = best - jnp.take_along_axis(ref, picks[..., None], axis=-1)[..., 0]
+            if precision == "f32":
+                return gap, jnp.zeros_like(gap)
+            low = self.forward(params, toks, m, precision)[0][:, P - 1 :]
+            top = jnp.argmax(low, axis=-1)
+            return gap, best - jnp.take_along_axis(ref, top[..., None], axis=-1)[..., 0]
+
+        gaps, ctrl = [], []
+        for r in range(0, seq.shape[0], rows_per_block):
+            g, c = block(params, seq[r : r + rows_per_block], served[r : r + rows_per_block])
+            gaps.append(np.asarray(g))
+            ctrl.append(np.asarray(c))
+        return np.concatenate(gaps), np.concatenate(ctrl)
 
 
 # -- the pod exchange and AdamW -------------------------------------------------------
@@ -229,43 +261,6 @@ def adamw(opt: Mapping, grads, m, v, params, step: int):
     return _ADAMW[key](grads, m, v, params, jnp.float32(step))
 
 
-def train_steps(params, batches: Sequence[Mapping[str, np.ndarray]], m: Mapping, opt: Mapping, *,
-                strategy: str, pods: int, precision: str, rows_per_block: int, devices: Sequence = ()):
-    """Follow the first ``len(batches)`` steps.  Returns per-step losses,
-    the per-leaf norms of the first step's clipped gradient and the final
-    parameters.  Buffers are donated from step to step, so that the
-    reference holds one copy of the parameters, each moment and the
-    gradient."""
-    params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
-    zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
-    mom, vel = zeros(params), zeros(params)
-    ef = [zeros(params) for _ in range(pods)]
-    losses: List[float] = []
-    first_grad = None
-    for k, batch in enumerate(batches, start=1):
-        rows = batch["tokens"].shape[0] // pods
-        pod_loss, pod_grads = [], []
-        for p in range(pods):
-            sl = slice(p * rows, (p + 1) * rows)
-            loss, g = loss_and_grad(params, batch["tokens"][sl], batch["labels"][sl], m,
-                                    precision, rows_per_block, devices)
-            pod_loss.append(loss)
-            pod_grads.append(g)
-        losses.append(sum(pod_loss) / pods)
-        if strategy == "hier_int8" and pods > 1:
-            boosted = [tree_add(g, e) for g, e in zip(pod_grads, ef)]
-            sent = [round_trip_tree(b) for b in boosted]
-            ef = [tree_sub(b, s) for b, s in zip(boosted, sent)]
-            grads = tree_mean(sent)
-        else:
-            grads = tree_mean(pod_grads)
-        params, mom, vel, clipped = adamw(opt, grads, mom, vel, params, k)
-        if first_grad is None:
-            first_grad = leaf_norms(clipped)
-        del clipped
-    return losses, first_grad, params
-
-
 # -- per-leaf norms --------------------------------------------------------------------
 
 
@@ -294,37 +289,3 @@ def change_norms(new, old) -> Dict[str, jnp.ndarray]:
 
 def to_host(norms: Mapping[str, jnp.ndarray]) -> Dict[str, float]:
     return {k: float(v) for k, v in jax.device_get(dict(norms)).items()}
-
-
-# -- serving ------------------------------------------------------------------------------
-
-
-def served_gaps(params, prompts: np.ndarray, served: np.ndarray, m: Mapping, *,
-                precision: str = "f32", rows_per_block: int = 1) -> Tuple[np.ndarray, np.ndarray]:
-    """For each served token, how far its reference logit lies below the
-    reference's best at that position.
-
-    prompts [R, P], served [R, G].  Returns ``(gap, control_gap)``, each [R, G]:
-    the gap of the served token, and (for ``precision != "f32"``) the gap of
-    the token the lower precision puts first at the same position.
-    """
-    P = prompts.shape[1]
-    seq = np.concatenate([prompts, served[:, :-1]], axis=1)
-
-    @jax.jit
-    def block(params, toks, picks):
-        ref = logits(params, toks, m, "f32")[:, P - 1 :]
-        best = ref.max(-1)
-        gap = best - jnp.take_along_axis(ref, picks[..., None], axis=-1)[..., 0]
-        if precision == "f32":
-            return gap, jnp.zeros_like(gap)
-        low = logits(params, toks, m, precision)[:, P - 1 :]
-        top = jnp.argmax(low, axis=-1)
-        return gap, best - jnp.take_along_axis(ref, top[..., None], axis=-1)[..., 0]
-
-    gaps, ctrl = [], []
-    for r in range(0, seq.shape[0], rows_per_block):
-        g, c = block(params, seq[r : r + rows_per_block], served[r : r + rows_per_block])
-        gaps.append(np.asarray(g))
-        ctrl.append(np.asarray(c))
-    return np.concatenate(gaps), np.concatenate(ctrl)

@@ -19,7 +19,7 @@ device, as in ``trace.load``, and its programs' runs are told apart by their
 program span (a program from before the spans) or no scope path, the readers
 read nothing.
 
-Both readings are per step, over the window's runs of the step program,
+The readings are per step, over the window's runs of the step program,
 found as ``step_gap_ms.train`` finds them: the module that took most of the
 device's time in the window.
 """
@@ -59,9 +59,14 @@ def scope_names(path: str) -> List[str]:
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _components(path: str) -> frozenset:
+    return frozenset(scope_names(path))
+
+
 def under(path: str, scopes: Iterable[str]) -> bool:
     """Whether one of ``scopes`` is a whole component of ``path``."""
-    return not set(scopes).isdisjoint(scope_names(path))
+    return not _components(path).isdisjoint(scopes)
 
 
 def _varint(buf, i: int) -> Tuple[int, int]:
@@ -221,6 +226,13 @@ def idle_under(rec, span: str) -> Optional[float]:
     return None if prog is None else gap_idle(prog, span, *rec.window_ns)
 
 
+def exposed_ms(rec, scopes: Iterable[str]) -> Optional[float]:
+    """Device ms per step run in the run's window in which only operations
+    under ``scopes`` run (``exposed_time``)."""
+    prog = of(rec)
+    return None if prog is None else exposed_time(prog, scopes, *rec.window_ns)
+
+
 def scope_ms(rec, scopes: Iterable[str]) -> Optional[float]:
     """Device ms per step run in the run's window under ``scopes``
     (``scope_time``)."""
@@ -240,8 +252,8 @@ def gap_idle(prog: Program, span: str, lo: float, hi: float) -> Optional[float]:
         runs = step_runs(dev, lo, hi)
         if len(runs) < 2:
             continue
-        busy = dev.busy()
-        idle = [g for a, b in zip(runs, runs[1:]) for g in tr.idle_intervals(busy, a[1], max(a[1], b[0]))]
+        busy = tr.Busy(dev.busy())
+        idle = [g for a, b in zip(runs, runs[1:]) for g in busy.idle(a[1], max(a[1], b[0]))]
         per.append(tr.overlap(idle, covered) / (len(runs) - 1))
     return sum(per) / len(per) / 1e6 if per else None
 
@@ -265,4 +277,49 @@ def scope_time(prog: Program, scopes: Iterable[str], lo: float, hi: float) -> Op
                 total, found = total + own, True
         if found:
             per.append(total / len(runs))
+    return sum(per) / len(per) / 1e6 if per else None
+
+
+def own_intervals(ops: Iterable[tr.Event]) -> List[Tuple[str, List[tr.Interval]]]:
+    """Each operation with the intervals of its own time: its span less the
+    operations nested in it (a loop's body runs inside the loop's event).
+    An operation that overlaps another without lying inside it is not nested."""
+    order = sorted(ops, key=lambda ev: (ev[1], -ev[2]))
+    out: List[Tuple[str, float, float, List[tr.Interval]]] = []
+    stack: List[int] = []
+    for name, s, e in order:
+        while stack and out[stack[-1]][2] < e:
+            stack.pop()
+        if stack:
+            out[stack[-1]][3].append((s, e))
+        out.append((name, s, e, []))
+        stack.append(len(out) - 1)
+    return [(name, tr.idle_intervals(inner, s, e) if inner else [(s, e)]) for name, s, e, inner in out]
+
+
+def exposed_time(prog: Program, scopes: Iterable[str], lo: float, hi: float) -> Optional[float]:
+    """Device time of the step runs in [lo, hi] in which an operation whose
+    path holds one of ``scopes`` runs and no other operation does, each
+    operation taken by its own time (``own_intervals``), in ms per run,
+    averaged over the devices; ``None`` where no operation of a step run
+    carries one of them."""
+    scopes = set(scopes)
+    per = []
+    for dev in prog.devices:
+        runs = step_runs(dev, lo, hi)
+        ops = prog.scopes.get(dev.name)
+        if not runs or not ops:
+            continue
+        starts = [s for s, _ in runs]
+        inside, outside = [], []
+        for path, own in own_intervals(ops):
+            if not under(path, scopes):
+                outside.extend(own)
+                continue
+            for s, e in own:
+                k = bisect.bisect_right(starts, s) - 1
+                if k >= 0 and s < runs[k][1]:
+                    inside.append((s, min(e, runs[k][1])))
+        if inside:
+            per.append(tr.uncovered(inside, outside) / len(runs))
     return sum(per) / len(per) / 1e6 if per else None

@@ -18,7 +18,7 @@ from benchmarks.chip import trace as tr  # noqa: E402
 MS = 1e6  # nanoseconds per ms: the hand-made events below are in ms
 WAITS = {"input_wait_ms.train": "repro.train.feed", "dispatch_wait_ms.train": "repro.train.dispatch",
          "fetch_wait_ms.train": "repro.train.fetch"}
-SCOPED = ("attention_ms.train", "head_loss_ms.train", "optimizer_ms.train")
+SCOPED = ("attention_ms.train", "head_loss_ms.train", "optimizer_ms.train", "collective_exposed_ms.train4")
 
 
 def _ms(events):
@@ -80,6 +80,43 @@ def test_idle_gaps_are_split_by_the_host_span_over_them():
 def test_scope_self_time_per_step_run(scopes, per_run):
     got = spans.scope_time(_program(), scopes, 0, 41 * MS)
     assert got == (None if per_run is None else pytest.approx(per_run))
+
+
+def _exchange():
+    """Two runs of the step program, (0, 10) and (15, 25), with the cross-pod
+    exchange in each: partly under compute, under ``wan_int8``, and nested in
+    a loop whose own time is not compute; a ``sync`` op between the runs."""
+    paths = _ms([
+        ("jit(inner)/transpose(jvp())/attention/dot_general", 0, 4),
+        ("jit(inner)/shard_map/sync/all-gather", 3, 6),  # 3..4 under compute
+        ("jit(inner)/shard_map/wan_int8/convert", 6, 7),
+        ("jit(inner)/adamw/mul", 7, 10),
+        ("jit(inner)/sync/all-reduce", 11, 12),  # between the runs
+        ("jit(inner)/while", 15, 22),
+        ("jit(inner)/while/body/sync/all-reduce", 16, 18),  # inside the loop's event
+        ("jit(inner)/while/body/ffn/dot_general", 18, 20),
+        ("jit(inner)/adamw/mul", 22, 25),
+    ])
+    dev = tr.Device("/device:TPU:0", ops=[("op", s, e) for _, s, e in paths],
+                    modules=_ms([("jit_inner(1)", 0, 10), ("jit_inner(1)", 15, 25)]))
+    return spans.Program(host=[], devices=[dev], scopes={dev.name: paths})
+
+
+def test_exposed_exchange_counts_only_time_no_other_operation_covers(monkeypatch):
+    prog = _exchange()
+    # run 1: 4..6 of the all-gather and 6..7 of wan_int8; run 2: 16..18
+    assert spans.exposed_time(prog, ["sync", "wan_int8"], 0, 26 * MS) == pytest.approx((3 + 2) / 2)
+    assert spans.exposed_time(prog, ["sync"], 0, 26 * MS) == pytest.approx((2 + 2) / 2)
+    assert spans.exposed_time(_program(), ["sync", "wan_int8"], 0, 41 * MS) is None
+    rec = types.SimpleNamespace(trace=object(), window_ns=(0, 26 * MS))
+    monkeypatch.setattr(spans, "of", lambda rec: prog)
+    assert harness.reader("collective_exposed_ms.train4")(rec) == pytest.approx(2.5)
+
+
+def test_own_intervals_leave_out_nested_operations():
+    ops = [("while", 0, 10), ("a", 1, 3), ("b", 2, 12), ("c", 13, 14)]
+    assert spans.own_intervals(ops) == [("while", [(0, 1), (3, 10)]), ("a", [(1, 3)]), ("b", [(2, 12)]),
+                                         ("c", [(13, 14)])]
 
 
 def test_scope_names_peel_transformations():

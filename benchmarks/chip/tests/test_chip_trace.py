@@ -38,19 +38,34 @@ def test_idle_between_program_runs():
     assert tr.idle_between_runs(runs, busy) == [2, 0, 4]
 
 
+def test_bisected_busy_intervals_agree_with_the_scan():
+    import random
+
+    rng = random.Random(3)
+    busy = [(s, s + rng.uniform(0.1, 5)) for s in (rng.uniform(0, 200) for _ in range(300))]
+    index = tr.Busy(busy)
+    for _ in range(200):
+        lo = rng.uniform(-5, 205)
+        hi = lo + rng.choice([0.0, rng.uniform(0, 3), rng.uniform(0, 40)])
+        assert index.clipped(lo, hi) == tr.merge(tr.clip(busy, lo, hi))
+        assert index.idle(lo, hi) == tr.idle_intervals(busy, lo, hi)
+        assert index.covered(lo, hi) == tr.overlap([(lo, hi)], busy)
+
+
 def test_collective_time_not_overlapped_by_compute():
-    dev = tr.Device(
-        "/device:TPU:0",
-        ops=[
-            ("fusion.1", 0, 10),
-            ("all-gather-start.3", 8, 14),  # 10..14 exposed
-            ("all-reduce.7", 20, 30),  # 20..25 hidden by compute, 25..30 exposed
-            ("convolution.2", 18, 25),
-            ("reduce-scatter.1", 40, 42),  # outside the window
-        ],
-        modules=[("jit_step(1)", 0, 31)],
-    )
-    assert tr.collective_exposed(dev, 0, 35) == 4 + 5
+    from benchmarks.chip import spans
+
+    ops = [
+        ("jit(step)/dot", 0, 10),
+        ("jit(step)/sync/all-gather-start", 8, 14),  # 10..14 exposed
+        ("jit(step)/sync/all-reduce", 20, 30),  # 20..25 hidden by compute, 25..30 exposed
+        ("jit(step)/ffn/convolution", 18, 25),
+        ("jit(step)/sync/reduce-scatter", 40, 42),  # outside the step's run
+    ]
+    dev = tr.Device("/device:TPU:0", ops=[(p.rsplit("/", 1)[1], s, e) for p, s, e in ops],
+                    modules=[("jit_step(1)", 0, 31)])
+    prog = spans.Program(host=[], devices=[dev], scopes={dev.name: ops})
+    assert spans.exposed_time(prog, ["sync"], 0, 35) == (4 + 5) / 1e6
     assert tr.main_program(dev, 0, 35) == "jit_step(1)"
 
 

@@ -22,14 +22,7 @@ _cell_spec = harness.cell_spec
 
 
 def tiny_spec(cell: str) -> dict:
-    if cell == TRAIN4:
-        # Two pods of two chips under hier_int8 have no cell yet: cell 1's
-        # model, traffic and limits (the work of each chip) on two pods.
-        spec = _cell_spec(TRAIN)
-        spec["cell"] = {**spec["cell"], "name": TRAIN4, "chips": 4}
-        spec["traffic"].update(pods=2, strategy="hier_int8")
-    else:
-        spec = _cell_spec(cell)
+    spec = _cell_spec(cell)
     t = spec["traffic"]
     if t["kind"] == "train":
         spec["config"]["model"].update(

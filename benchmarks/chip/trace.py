@@ -17,10 +17,11 @@ The rest are pure functions over ``(start, end)`` intervals.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
+import functools
 import glob
 import os
-import re
 from collections import defaultdict
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -28,11 +29,6 @@ Interval = Tuple[float, float]
 Event = Tuple[str, float, float]  # (name, start_ns, end_ns)
 
 HOST_PREFIX = "bench."
-COLLECTIVE = re.compile(
-    r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
-    r"|allreduce|allgather|reducescatter|send|recv",
-    re.IGNORECASE,
-)
 
 
 # -- interval arithmetic ---------------------------------------------------------
@@ -95,6 +91,42 @@ def idle_intervals(busy: Iterable[Interval], lo: float, hi: float) -> List[Inter
     return out
 
 
+class Busy:
+    """Intervals merged once, with their ends kept for bisection, so that the
+    part of them within a short span is found without scanning them all."""
+
+    def __init__(self, intervals: Iterable[Interval]):
+        self.merged = merge(intervals)
+        self.ends = [e for _, e in self.merged]
+
+    def clipped(self, lo: float, hi: float) -> List[Interval]:
+        """``clip(merged, lo, hi)``."""
+        out: List[Interval] = []
+        if hi <= lo:
+            return out
+        for k in range(bisect.bisect_right(self.ends, lo), len(self.merged)):
+            s, e = self.merged[k]
+            if s >= hi:
+                break
+            out.append((max(s, lo), min(e, hi)))
+        return out
+
+    def covered(self, lo: float, hi: float) -> float:
+        """Length of [lo, hi] that the intervals cover."""
+        return sum(e - s for s, e in self.clipped(lo, hi))
+
+    def idle(self, lo: float, hi: float) -> List[Interval]:
+        """``idle_intervals(merged, lo, hi)``."""
+        out, t = [], lo
+        for s, e in self.clipped(lo, hi):
+            if s > t:
+                out.append((t, s))
+            t = max(t, e)
+        if hi > t:
+            out.append((t, hi))
+        return out
+
+
 # -- the trace --------------------------------------------------------------------
 
 
@@ -125,6 +157,7 @@ class Trace:
         return None
 
 
+@functools.lru_cache(maxsize=None)  # a step's few thousand names recur in every step
 def op_name(hlo: str) -> str:
     """``%fusion.12 = bf16[8,1024]{...} fusion(...), kind=...`` -> ``fusion.12 fusion``.
 
@@ -259,14 +292,6 @@ def idle_gaps(trace: Trace, lo: float, hi: float, k: int = 10) -> List[List]:
     return [[host_activity(trace, g), (g[1] - g[0]) / 1e9] for g in gaps]
 
 
-def collective_exposed(device: Device, lo: float, hi: float) -> float:
-    """Nanoseconds in [lo, hi] in which a collective ran on ``device`` and no
-    other operation did."""
-    coll = [(s, e) for n, s, e in device.ops if COLLECTIVE.search(n)]
-    other = [(s, e) for n, s, e in device.ops if not COLLECTIVE.search(n)]
-    return uncovered(clip(coll, lo, hi), other)
-
-
 def main_program(device: Device, lo: float, hi: float) -> Optional[str]:
     """The module that took most of ``device``'s time in [lo, hi]."""
     total: Dict[str, float] = defaultdict(float)
@@ -285,10 +310,11 @@ def idle_between_runs(runs: Sequence[Interval], busy: Sequence[Interval]) -> Lis
     """For each pair of consecutive runs, the idle time between them: the
     gap from one's end to the next one's start, less any operation in it."""
     runs = sorted(runs)
+    busy = Busy(busy)
     out = []
     for a, b in zip(runs, runs[1:]):
         gap = (a[1], max(a[1], b[0]))
-        out.append((gap[1] - gap[0]) - overlap([gap], busy))
+        out.append((gap[1] - gap[0]) - busy.covered(*gap))
     return out
 
 
