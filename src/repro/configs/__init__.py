@@ -1,4 +1,5 @@
-"""Architecture registry: the ten assigned archs + the paper's own model.
+"""Architecture registry: the ten assigned archs, the paper's own model and
+Mellum2-12B-A2.5B, a mixture of experts with a one-chip stage.
 
 Every architecture is selectable via ``--arch <id>`` in the launchers.
 ``EXPECTED_PARAMS`` records the published total parameter counts used by
@@ -25,9 +26,13 @@ _MODULES = {
     "musicgen-large": "musicgen_large",
     "recurrentgemma-9b": "recurrentgemma_9b",
     "distilgpt2-82m": "distilgpt2_82m",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
 }
 
-ASSIGNED_ARCHS: Tuple[str, ...] = tuple(k for k in _MODULES if k != "distilgpt2-82m")
+#: The ten assigned archs; the paper's model and Mellum2 are extra.
+ASSIGNED_ARCHS: Tuple[str, ...] = tuple(
+    k for k in _MODULES if k not in ("distilgpt2-82m", "mellum2-12b-a2.5b")
+)
 ALL_ARCHS: Tuple[str, ...] = tuple(_MODULES)
 
 #: Published total parameter counts (backbone scope for vlm/audio).
@@ -43,6 +48,7 @@ EXPECTED_PARAMS: Dict[str, float] = {
     "musicgen-large": 2.4e9,  # 3.3B total minus the (stubbed) T5 text encoder
     "recurrentgemma-9b": 9.4e9,
     "distilgpt2-82m": 82e6,
+    "mellum2-12b-a2.5b": 12e9,
 }
 
 

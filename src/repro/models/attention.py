@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
-from .config import ModelConfig
+from .config import ModelConfig, YarnConfig
 from .layers import apply_rope, dense_init, softcap
 
 Params = Dict[str, jnp.ndarray]
@@ -291,6 +291,11 @@ def _sdpa_flash(q, k, v, *, window, logit_softcap, interpret: bool = False):
     zero, which the keys' gradient (a key bias under rotary positions)
     collects.  Each device runs the kernel on its own rows and heads
     (``map_batch_shards``).
+
+    Grouped-query attention (KVH < H) goes to the same kernel unchanged:
+    splash reads key and value head ``h // (H // KVH)`` for query head
+    ``h``, forward and in the fused backward, which sums each key and value
+    head's gradient over its group of query heads.
     """
     from repro.distributed.act_sharding import map_batch_shards
 
@@ -340,6 +345,7 @@ def attention_forward(
     cfg: ModelConfig,
     *,
     window: Optional[int],
+    yarn: Optional[YarnConfig] = None,  # the layer's rotary (default without)
     return_cache: bool = False,
     cache_len: Optional[int] = None,  # total decode capacity (>= S)
 ):
@@ -353,8 +359,9 @@ def attention_forward(
     # gathered across the sequence — d_model/(2*kv*hd) ~ 7x fewer bytes
     # than all-gathering activations (§Perf yi-34b iteration 2).
     k, v = replicate_seq(k), replicate_seq(v)
-    q = apply_rope(q, positions, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
-    k = apply_rope(k, positions, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
+    rope = dict(theta=cfg.rope_theta, fraction=cfg.rope_fraction, yarn=yarn)
+    q = apply_rope(q, positions, **rope)
+    k = apply_rope(k, positions, **rope)
     out = causal_self_attention(q, k, v, cfg, window=window)
     y = _out_proj(params, out, cfg)
     if not return_cache:
@@ -415,12 +422,14 @@ def attention_decode(
     position,  # scalar int32: absolute position of the new token
     *,
     window: Optional[int],
+    yarn: Optional[YarnConfig] = None,
 ):
     """One decode step against (and updating) a rolling KV cache."""
     q, k_new, v_new = _project_qkv(params, x_t, cfg)
     pos_arr = jnp.full((1,), position, dtype=jnp.int32)
-    q = apply_rope(q, pos_arr, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
-    k_new = apply_rope(k_new, pos_arr, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
+    rope = dict(theta=cfg.rope_theta, fraction=cfg.rope_fraction, yarn=yarn)
+    q = apply_rope(q, pos_arr, **rope)
+    k_new = apply_rope(k_new, pos_arr, **rope)
     size = cache["k"].shape[1]
     slot = position % size  # rolling for windows; affine for full caches
     with jax.named_scope("kv_cache"):

@@ -11,7 +11,7 @@ keeps ``lax.scan``-over-layers applicable to heterogeneous stacks.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Mapping, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -31,9 +31,51 @@ class MoEConfig:
     # Arctic: a small dense FFN runs in parallel with the MoE ("dense residual")
     parallel_dense: bool = False
     # router implementation: "einsum" (GShard dispatch/combine einsums, robust
-    # GSPMD sharding) or "gather" (sort/gather based, true-FLOPs path)
+    # GSPMD sharding), "gather" (sort/gather based, true-FLOPs path), or
+    # "held": the dropless layer of the ``held`` experts from ``first_held``
+    # on, the share of an expert-parallel layer that one chip holds (the
+    # router still scores all ``num_experts``; ``capacity_factor`` unused)
     impl: str = "einsum"
     router_aux_coef: float = 0.01
+    held: Optional[int] = None  # "held" only; None holds every expert
+    first_held: int = 0
+
+    def __post_init__(self):
+        if self.impl not in ("einsum", "gather", "held"):
+            raise ValueError(f"unknown moe impl {self.impl!r}")
+        if self.impl != "held" and (self.held is not None or self.first_held):
+            raise ValueError("held and first_held belong to impl='held'")
+        if not 0 <= self.first_held < self.first_held + self.num_held <= self.num_experts:
+            raise ValueError("the held experts must lie within the router's num_experts")
+
+    @property
+    def num_held(self) -> int:
+        """Experts whose weights this layer holds and computes."""
+        return self.num_experts if self.held is None else self.held
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (Peng et al. 2023, arXiv 2309.00071), as the
+    ``yarn`` rope type of Hugging Face transformers: frequencies ramped
+    between interpolated (by ``factor``) and original ones over the
+    dimensions that turn between ``beta_slow`` and ``beta_fast`` times in
+    ``original_max_position_embeddings``, and cos/sin scaled by
+    ``attention_factor`` (default ``0.1 ln(factor) + 1``).  ``kinds``: the
+    layer kinds whose rotary it scales, as a source's per-layer-type rope
+    parameters give them; the other kinds keep the default rotary."""
+
+    kinds: Tuple[str, ...]
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    def __post_init__(self):
+        object.__setattr__(self, "kinds", tuple(self.kinds))
+        if not self.kinds or not set(self.kinds) <= {ATTN, LOCAL}:
+            raise ValueError(f"yarn kinds must name attention kinds, got {self.kinds!r}")
 
 
 @dataclass(frozen=True)
@@ -57,6 +99,9 @@ class ModelConfig:
     local_window: int = 2048  # window for LOCAL layers
     rope_theta: float = 10_000.0
     rope_fraction: float = 1.0  # chatglm applies rotary to half the head dim
+    # YaRN on the rotary of the layer kinds in ``yarn.kinds`` (Mellum2: ATTN,
+    # its full layers; its sliding LOCAL layers keep the default rotary)
+    yarn: Optional[YarnConfig] = None
     attn_logit_softcap: Optional[float] = None
     # attention execution (models/attention.py): "auto" runs training and
     # prefill attention in JAX's Pallas splash kernel when compiled for a TPU
@@ -69,14 +114,18 @@ class ModelConfig:
     attn_block: int = 512
 
     # norms / activations
-    norm: str = "rmsnorm"  # rmsnorm | layernorm | nonparametric_ln (OLMo)
+    # rmsnorm (its weight stored as gain - 1, Gemma's form) | rmsnorm_gain (the
+    # gain itself, as Llama-family checkpoints store it) | layernorm |
+    # nonparametric_ln (OLMo)
+    norm: str = "rmsnorm"
     activation: str = "swiglu"  # swiglu | geglu | gelu | relu_sq
     parallel_block: bool = False  # attn+ffn in parallel (not used by defaults)
     tie_embeddings: bool = False
     use_bias_attn: bool = False  # starcoder2 / chatglm3 qkv bias
     use_bias_mlp: bool = False  # starcoder2
 
-    # MoE
+    # MoE: the FFN of every ATTN and LOCAL layer; a mapping of MoEConfig's
+    # fields is accepted (as a configuration file gives it), as for ``yarn``
     moe: Optional[MoEConfig] = None
 
     # RWKV6 / RG-LRU
@@ -99,10 +148,14 @@ class ModelConfig:
     z_loss: float = 1e-4
 
     # training-time behaviour
-    remat: str = "none"  # none | full | dots  — activation checkpoint policy
+    remat: str = "none"  # none | full | block | dots  — activation checkpoint policy
     scan_layers: bool = True
 
     def __post_init__(self):
+        if isinstance(self.moe, Mapping):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
+        if isinstance(self.yarn, Mapping):
+            object.__setattr__(self, "yarn", YarnConfig(**self.yarn))
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.d_rnn is None:
@@ -159,16 +212,18 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: only routed experts)."""
+        """Params touched per token (MoE: only routed experts; of the experts
+        held here, a token reaches ``held * k / num_experts`` on average)."""
         if self.moe is None:
             return self.param_count()
-        full = self.param_count()
-        ffn = self._ffn_expert_params()
-        inactive = (self.moe.num_experts - self.moe.num_experts_per_tok) * ffn
+        moe = self.moe
+        active = moe.num_held * moe.num_experts_per_tok / moe.num_experts
+        inactive = round((moe.num_held - active) * self._ffn_expert_params())
         n_moe_layers = sum(
-            1 for k in (list(self.pattern) * self.num_groups + list(self.remainder)) if k == ATTN
+            1 for k in (list(self.pattern) * self.num_groups + list(self.remainder))
+            if k in (ATTN, LOCAL)
         )
-        return full - inactive * n_moe_layers
+        return self.param_count() - inactive * n_moe_layers
 
     def _ffn_expert_params(self) -> int:
         d, f = self.d_model, self.d_ff
@@ -182,8 +237,8 @@ class ModelConfig:
         norms = 2 * d if self.norm != "nonparametric_ln" else 0
         if kind in (ATTN, LOCAL):
             attn = d * q + 2 * d * kv + q * d
-            if self.moe is not None and kind == ATTN:
-                ffn = self.moe.num_experts * self._ffn_expert_params()
+            if self.moe is not None:
+                ffn = self.moe.num_held * self._ffn_expert_params()
                 ffn += d * self.moe.num_experts  # router
                 if self.moe.parallel_dense:
                     ffn += self._ffn_expert_params()

@@ -13,12 +13,20 @@ Two MoE dispatch implementations, selectable per config:
 * ``gather`` — argsort/gather based dispatch that avoids the one-hot
   matmuls entirely (true-FLOPs path, used in the §Perf hillclimb).
 
+* ``held`` — the dropless layer of the experts one chip holds in an
+  expert-parallel deployment (``MoEConfig.held`` from ``first_held``): the
+  router scores every expert, every (token, held expert) pair is computed
+  with no capacity, in matmuls grouped over the held experts, and pairs of
+  experts held elsewhere add nothing here.  Its named scopes are
+  ``moe_router``, ``moe_dispatch``, ``moe_experts`` and ``moe_combine``.
+
 Arctic's "dense residual" (a small dense FFN in parallel with the MoE) is
 supported via ``MoEConfig.parallel_dense``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -84,12 +92,12 @@ def dense_ffn(params: Params, x, cfg: ModelConfig):
 def init_moe(key, cfg: ModelConfig) -> Params:
     assert cfg.moe is not None
     moe = cfg.moe
-    d, f, e = cfg.d_model, cfg.d_ff, moe.num_experts
+    d, f, e = cfg.d_model, cfg.d_ff, moe.num_held
     pdt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 5)
     glu = cfg.activation in ("swiglu", "geglu")
     params: Params = {
-        "router": dense_init(ks[0], (d, e), dtype=jnp.float32),
+        "router": dense_init(ks[0], (d, moe.num_experts), dtype=jnp.float32),
         "w_up": dense_init(ks[1], (e, d, f), in_axis_size=d, dtype=pdt),
         "w_down": dense_init(ks[2], (e, f, d), in_axis_size=f, dtype=pdt),
     }
@@ -101,7 +109,10 @@ def init_moe(key, cfg: ModelConfig) -> Params:
 
 
 def _router_probs(params: Params, x_flat, moe: MoEConfig):
-    """Router softmax in fp32 + top-k selection with renormalized gates.
+    """Router logits and softmax in fp32 (the logits' matmul at ``HIGHEST``,
+    which a TPU would otherwise take in one bf16 pass, so that a top-k choice
+    does not hang on the router weights' rounding) + top-k selection with
+    renormalized gates.
 
     Indices come from a stop-gradient top_k; gate values are recovered by
     one-hot contraction against the differentiable softmax.  This keeps
@@ -109,7 +120,9 @@ def _router_probs(params: Params, x_flat, moe: MoEConfig):
     backward, which XLA's SPMD partitioner cannot handle beneath a manual
     "pod" sub-mesh (same CHECK failure as sharded gathers).
     """
-    logits = x_flat.astype(jnp.float32) @ params["router"]
+    logits = jnp.matmul(
+        x_flat.astype(jnp.float32), params["router"], precision=jax.lax.Precision.HIGHEST
+    )
     probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
     _, expert_idx = jax.lax.top_k(
         jax.lax.stop_gradient(probs), moe.num_experts_per_tok
@@ -264,18 +277,155 @@ def _moe_gather(params: Params, x_flat, cfg: ModelConfig):
     return out.astype(x_flat.dtype), aux
 
 
-def moe_ffn(params: Params, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """MoE feed-forward over x: [B, S, D] -> ([B, S, D], aux_loss)."""
+# -- the held experts' dropless layer --------------------------------------------
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+@jax.custom_vjp
+def _dispatch(x, src, slot, held):
+    """Row ``r`` of the pair buffer is token ``src[r]``: ``x[src]``.
+
+    Its gradient gathers too, where autodiff would scatter-add: each token
+    sums the buffer rows ``slot[t, j]`` of its held pairs.
+    """
+    return x[src]
+
+
+def _dispatch_fwd(x, src, slot, held):
+    return x[src], (slot, held)
+
+
+def _dispatch_bwd(res, g):
+    slot, held = res
+    rows = jnp.where(held[..., None], g[slot], 0)  # [t, k, D]
+    return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, w, slot, held, pair):
+    """``y[t] = sum_j w[t, j] * ys[slot[t, j]]`` over token t's held pairs.
+
+    Rows of ``ys`` past the held pairs are undefined (the grouped matmul
+    leaves them), so they are selected away, never multiplied.  ``pair[r]``
+    is the (token, choice) pair ``t * k + j`` of buffer row ``r`` (``t * k``
+    on padding); the gradient gathers by it.
+    """
+    rows = jnp.where(held[..., None], ys[slot], 0).astype(jnp.float32)
+    return jnp.sum(w[..., None] * rows, axis=1).astype(ys.dtype)
+
+
+def _combine_fwd(ys, w, slot, held, pair):
+    return _combine(ys, w, slot, held, pair), (ys, w, slot, held, pair)
+
+
+def _combine_bwd(res, dy):
+    ys, w, slot, held, pair = res
+    t, k = w.shape
+    rows = jnp.where(held[..., None], ys[slot], 0).astype(jnp.float32)
+    dw = jnp.sum(dy[:, None, :].astype(jnp.float32) * rows, axis=-1)
+    held_r = jnp.append(held.reshape(-1), False)[pair]
+    w_r = jnp.append(w.reshape(-1), 0.0)[pair]
+    dys = jnp.where(held_r[:, None], w_r[:, None] * dy[jnp.minimum(pair // k, t - 1)], 0)
+    return dys.astype(ys.dtype), dw.astype(w.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """Tiles of the Pallas grouped matmul: 512 rows (or 128), and for each
+    of the other two dimensions its widest divisor that is a multiple of
+    128 and at most 1152 (all of it, below 128)."""
+
+    def tile(size: int) -> int:
+        return next((t for t in range(min(size, 1152) // 128 * 128, 0, -128) if size % t == 0), size)
+
+    return (512 if m % 512 == 0 else 128), tile(k), tile(n)
+
+
+def grouped_matmul_kernel(x, w, sizes, *, interpret: bool = False):
+    """``x[rows of group g] @ w[g]`` in the Pallas megablox grouped matmul
+    (forward and backward), which visits only the tiles that hold the groups'
+    rows; rows past the groups are left undefined."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops
+
+    return ops.gmm(x, w, sizes, x.dtype, _gmm_tiling, None, None, False, interpret)
+
+
+def grouped_matmul(x, w, sizes):
+    """x: [m, K] rows grouped by ``sizes`` [G] (in order, sum <= m);
+    w: [G, K, N].  The Pallas kernel when compiled for a TPU, else
+    ``jax.lax.ragged_dot``."""
+
+    def jnp_form(x, w, sizes):
+        return jax.lax.ragged_dot(x, w, sizes)
+
+    return jax.lax.platform_dependent(x, w, sizes, tpu=grouped_matmul_kernel, default=jnp_form)
+
+
+def _held_expert_ffn(params: Params, xs, sizes, cfg: ModelConfig):
+    """xs: [m, D] rows grouped by held expert -> [m, D]."""
+    dt = cfg.compute_dtype
+    mm = functools.partial(grouped_matmul, sizes=sizes)
+    up = mm(xs, params["w_up"].astype(dt))
+    if cfg.activation in ("swiglu", "geglu"):
+        inner = jax.nn.silu if cfg.activation == "swiglu" else jax.nn.gelu
+        h = inner(mm(xs, params["w_gate"].astype(dt))) * up
+    else:
+        h = activation_fn(cfg.activation)(up)
+    return mm(h, params["w_down"].astype(dt))
+
+
+def _moe_held(params: Params, x_flat, cfg: ModelConfig):
+    """Dropless layer of the held experts.  Returns (y, aux, pairs computed)."""
+    moe = cfg.moe
+    t, _ = x_flat.shape
+    k, h = moe.num_experts_per_tok, moe.num_held
+    with jax.named_scope("moe_router"):
+        probs, gates, expert_idx = _router_probs(params, x_flat, moe)
+        aux = _aux_loss(probs, expert_idx, moe)
+    with jax.named_scope("moe_dispatch"):
+        local = expert_idx - moe.first_held
+        held = (local >= 0) & (local < h)  # [t, k]
+        key = jnp.where(held, local, h).reshape(-1)
+        order = jnp.argsort(key, stable=True)  # held pairs first, by expert
+        slot = jnp.argsort(order).reshape(t, k)  # buffer row of each pair
+        sizes = jnp.sum(jax.nn.one_hot(key, h, dtype=jnp.int32), axis=0)
+        # every held pair has a row: a token holds at most min(k, h) of them
+        rows = _round_up(t * min(k, h), 128)
+        pair = jnp.pad(order, (0, max(rows - t * k, 0)), constant_values=t * k)[:rows]
+        slot = jnp.where(held, slot, rows - 1)
+        xs = _dispatch(x_flat, jnp.minimum(pair // k, t - 1), slot, held)
+    with jax.named_scope("moe_experts"):
+        ys = _held_expert_ffn(params, xs, sizes, cfg)
+    with jax.named_scope("moe_combine"):
+        y = _combine(ys, jnp.where(held, gates, 0.0), slot, held, pair)
+    return y, aux, jnp.sum(sizes)
+
+
+def moe_ffn(params: Params, x, cfg: ModelConfig):
+    """MoE feed-forward over x: [B, S, D] -> ([B, S, D], aux_loss, pairs).
+
+    ``pairs`` counts the (token, expert) pairs the held layer computed, and
+    is None for the other forms.
+    """
     assert cfg.moe is not None
     b, s, d = x.shape
     x_flat = x.reshape(b * s, d)
+    pairs = None
     if cfg.moe.impl == "einsum":
         y, aux = _moe_einsum(params, x_flat, cfg)
     elif cfg.moe.impl == "gather":
         y, aux = _moe_gather(params, x_flat, cfg)
     else:
-        raise ValueError(f"unknown moe impl {cfg.moe.impl!r}")
+        y, aux, pairs = _moe_held(params, x_flat, cfg)
     y = y.reshape(b, s, d)
     if cfg.moe.parallel_dense:
         y = y + dense_ffn(params["dense"], x, cfg)
-    return y, aux
+    return y, aux, pairs

@@ -8,7 +8,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .config import ModelConfig
+from .config import ModelConfig, YarnConfig
 
 
 # -- initializers -------------------------------------------------------------
@@ -28,8 +28,9 @@ def embed_init(key, shape, dtype=jnp.float32):
 # -- norms ---------------------------------------------------------------------
 
 
-def rms_norm(x, scale, eps: float = 1e-6):
-    """RMSNorm with f32 *reduction* but compute-dtype elementwise math.
+def rms_norm(x, scale, eps: float = 1e-6, offset: float = 1.0):
+    """RMSNorm with f32 *reduction* but compute-dtype elementwise math; the
+    gain is ``offset + scale`` (``offset`` 0 where ``scale`` is the gain).
 
     Upcasting the whole activation to f32 (the naive formulation) makes
     XLA materialize — and, under sequence parallelism, ALL-GATHER — f32
@@ -41,7 +42,7 @@ def rms_norm(x, scale, eps: float = 1e-6):
     inv = jax.lax.rsqrt(var + eps).astype(x.dtype)
     out = x * inv
     if scale is not None:
-        out = out * (1.0 + scale).astype(x.dtype)
+        out = out * (offset + scale).astype(x.dtype)
     return out
 
 
@@ -71,6 +72,8 @@ def init_norm(key, cfg: ModelConfig):
             "scale": jnp.ones((cfg.d_model,), jnp.float32),
             "bias": jnp.zeros((cfg.d_model,), jnp.float32),
         }
+    if cfg.norm == "rmsnorm_gain":  # the gain itself, as Llama-family checkpoints hold it
+        return {"scale": jnp.ones((cfg.d_model,), jnp.float32)}
     # rmsnorm: stored as (scale - 1) so zeros-init is identity
     return {"scale": jnp.zeros((cfg.d_model,), jnp.float32)}
 
@@ -80,6 +83,8 @@ def apply_norm(params, x, cfg: ModelConfig):
         return nonparametric_ln(x)
     if cfg.norm == "layernorm":
         return layer_norm(x, params["scale"], params["bias"])
+    if cfg.norm == "rmsnorm_gain":
+        return rms_norm(x, params["scale"], offset=0.0)
     return rms_norm(x, params["scale"])
 
 
@@ -94,23 +99,59 @@ def rope_frequencies(head_dim: int, fraction: float, theta: float):
     return inv, rot_dim
 
 
-def apply_rope(x, positions, *, theta: float = 10_000.0, fraction: float = 1.0):
+def yarn_frequencies(head_dim: int, fraction: float, theta: float, yarn: YarnConfig):
+    """YaRN's inverse frequencies and attention factor (``YarnConfig``).
+
+    Dimension pair ``i`` turns ``L / (2 pi) * inv[i]`` times over the original
+    context ``L``: pairs that turn more than ``beta_fast`` times keep their
+    frequency, those under ``beta_slow`` are interpolated by ``factor``, and a
+    linear ramp over the pair index joins the two, its ends rounded outwards.
+    """
+    inv, rot_dim = rope_frequencies(head_dim, fraction, theta)
+
+    def pair_turning(turns: float) -> float:  # the pair that turns ``turns`` times
+        orig = yarn.original_max_position_embeddings
+        return rot_dim * math.log(orig / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_turning(yarn.beta_fast)), 0)
+    high = min(math.ceil(pair_turning(yarn.beta_slow)), rot_dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(rot_dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0
+    )
+    inv = inv / yarn.factor * ramp + inv * (1.0 - ramp)
+    factor = yarn.attention_factor
+    if factor is None:
+        factor = 0.1 * math.log(yarn.factor) + 1.0 if yarn.factor > 1 else 1.0
+    return inv, rot_dim, factor
+
+
+def apply_rope(
+    x, positions, *, theta: float = 10_000.0, fraction: float = 1.0,
+    yarn: Optional[YarnConfig] = None,
+):
     """Rotary embedding over the leading ``fraction`` of the head dim.
 
     ``fraction=1.0`` is standard (llama/starcoder); ``fraction=0.5`` is the
     ChatGLM "2d" convention where only half of each head rotates and the
-    other half carries position-free content.
+    other half carries position-free content.  With ``yarn`` the
+    frequencies and the cos/sin scale are YaRN's (:func:`yarn_frequencies`).
 
     x: [..., seq, heads, head_dim]; positions: [..., seq]
     """
     head_dim = x.shape[-1]
-    inv, rot_dim = rope_frequencies(head_dim, fraction, theta)
+    if yarn is None:
+        inv, rot_dim = rope_frequencies(head_dim, fraction, theta)
+        scale = None
+    else:
+        inv, rot_dim, scale = yarn_frequencies(head_dim, fraction, theta, yarn)
     if rot_dim == 0:
         return x
     x_rot, x_pass = x[..., :rot_dim], x[..., rot_dim:]
     angles = positions[..., None].astype(jnp.float32) * inv  # [..., seq, rot/2]
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return jnp.concatenate([rotated.astype(x.dtype), x_pass], axis=-1)

@@ -16,8 +16,10 @@ Entry points (all pure functions over a params pytree):
 
 Named scopes (``jax.named_scope``: compile-time metadata, which a profile
 shows as each operation's path) mark ``embed``, ``layers`` (the stack's own
-work), ``attention`` and ``ffn`` in each block, ``head``, ``loss``,
-``kv_cache`` and the whole of ``prefill`` and ``decode``.
+work), ``attention`` and ``ffn`` in each block (inside a held-expert
+``ffn``: ``moe_router``, ``moe_dispatch``, ``moe_experts``,
+``moe_combine``), ``head``, ``loss``, ``kv_cache`` and the whole of
+``prefill`` and ``decode``.
 """
 
 from __future__ import annotations
@@ -51,7 +53,7 @@ def _init_layer(key, kind: str, cfg: ModelConfig) -> Params:
     params: Params = {"norm1": init_norm(k3, cfg), "norm2": init_norm(k4, cfg)}
     if kind in (ATTN, LOCAL):
         params["attn"] = init_attention(k1, cfg)
-        if cfg.moe is not None and kind == ATTN:
+        if cfg.moe is not None:
             params["ffn"] = init_moe(k2, cfg)
         else:
             params["ffn"] = init_dense_ffn(k2, cfg)
@@ -112,20 +114,28 @@ def _layer_window(kind: str, cfg: ModelConfig) -> Optional[int]:
     return None
 
 
+def _layer_attention(kind: str, cfg: ModelConfig) -> Dict[str, Any]:
+    """The window and the rotary of a layer kind: YaRN's in the kinds
+    ``cfg.yarn`` names, else the default."""
+    yarn = cfg.yarn if cfg.yarn is not None and kind in cfg.yarn.kinds else None
+    return {"window": _layer_window(kind, cfg), "yarn": yarn}
+
+
 def _block_train(params: Params, x, kind: str, cfg: ModelConfig):
-    """One layer (training/prefill, no cache). Returns (x, aux, cache)."""
+    """One layer (training/prefill, no cache). Returns (x, aux, pairs):
+    the MoE balance loss, and the (token, expert) pairs a held-expert layer
+    computed (None for every other layer)."""
     aux = jnp.zeros((), jnp.float32)
+    pairs = None
     if kind in (ATTN, LOCAL):
         with jax.named_scope("attention"):
             h = apply_norm(params["norm1"], x, cfg)
-            attn_out, _ = attention_forward(
-                params["attn"], h, cfg, window=_layer_window(kind, cfg)
-            )
+            attn_out, _ = attention_forward(params["attn"], h, cfg, **_layer_attention(kind, cfg))
         x = x + attn_out
         with jax.named_scope("ffn"):
             h = apply_norm(params["norm2"], x, cfg)
-            if cfg.moe is not None and kind == ATTN:
-                ffn_out, aux = moe_ffn(params["ffn"], h, cfg)
+            if cfg.moe is not None:
+                ffn_out, aux, pairs = moe_ffn(params["ffn"], h, cfg)
             else:
                 ffn_out = dense_ffn(params["ffn"], h, cfg)
         x = x + ffn_out
@@ -151,7 +161,7 @@ def _block_train(params: Params, x, kind: str, cfg: ModelConfig):
         x = x + cm_out
     else:
         raise ValueError(kind)
-    return x, aux
+    return x, aux, pairs
 
 
 def _block_prefill(params: Params, x, kind: str, cfg: ModelConfig, max_len: int):
@@ -160,14 +170,14 @@ def _block_prefill(params: Params, x, kind: str, cfg: ModelConfig, max_len: int)
         with jax.named_scope("attention"):
             h = apply_norm(params["norm1"], x, cfg)
             attn_out, cache = attention_forward(
-                params["attn"], h, cfg,
-                window=_layer_window(kind, cfg), return_cache=True, cache_len=max_len,
+                params["attn"], h, cfg, **_layer_attention(kind, cfg),
+                return_cache=True, cache_len=max_len,
             )
         x = x + attn_out
         with jax.named_scope("ffn"):
             h = apply_norm(params["norm2"], x, cfg)
-            if cfg.moe is not None and kind == ATTN:
-                ffn_out, _ = moe_ffn(params["ffn"], h, cfg)
+            if cfg.moe is not None:
+                ffn_out = moe_ffn(params["ffn"], h, cfg)[0]
             else:
                 ffn_out = dense_ffn(params["ffn"], h, cfg)
         x = x + ffn_out
@@ -203,13 +213,13 @@ def _block_decode(params: Params, x_t, cache, kind: str, cfg: ModelConfig, posit
         with jax.named_scope("attention"):
             h = apply_norm(params["norm1"], x_t, cfg)
             attn_out, cache = attention_decode(
-                params["attn"], h, cache, cfg, position, window=_layer_window(kind, cfg)
+                params["attn"], h, cache, cfg, position, **_layer_attention(kind, cfg)
             )
         x_t = x_t + attn_out
         with jax.named_scope("ffn"):
             h = apply_norm(params["norm2"], x_t, cfg)
-            if cfg.moe is not None and kind == ATTN:
-                ffn_out, _ = moe_ffn(params["ffn"], h, cfg)
+            if cfg.moe is not None:
+                ffn_out = moe_ffn(params["ffn"], h, cfg)[0]
             else:
                 ffn_out = dense_ffn(params["ffn"], h, cfg)
         return x_t + ffn_out, cache
@@ -271,7 +281,7 @@ def unembed(params: Params, x, cfg: ModelConfig):
 
 
 def _maybe_remat(fn, cfg: ModelConfig):
-    if cfg.remat == "full":
+    if cfg.remat in ("full", "block"):
         return jax.checkpoint(fn)
     if cfg.remat == "dots":
         return jax.checkpoint(
@@ -280,43 +290,66 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return fn
 
 
-def forward(params: Params, batch, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Full forward pass -> (logits [B, S, V], moe_aux scalar)."""
+def _forward(params: Params, batch, cfg: ModelConfig):
+    """Full forward pass -> (logits [B, S, V], moe_aux scalar, pairs): the
+    (token, expert) pairs the held-expert layers computed, summed over the
+    layers (None where no layer is one)."""
     x = embed_inputs(params, batch, cfg)
 
     from repro.distributed.act_sharding import shard_activations
 
+    def add(pairs, p):
+        return pairs if p is None else pairs + p
+
+    # remat "full" checkpoints the scan body, one group of ``pattern``;
+    # "block" each block inside it, so that a backward holds the residuals
+    # of one layer, not of a whole group
+    per_block = cfg.remat == "block"
+
+    def block(params, x, kind):
+        fn = lambda params, x: _block_train(params, x, kind, cfg)  # noqa: E731
+        return (_maybe_remat(fn, cfg) if per_block else fn)(params, x)
+
     def group_body(carry, slot_params):
-        x, aux = carry
+        x, aux, pairs = carry
         for s, kind in enumerate(cfg.pattern):
-            x, a = _block_train(slot_params[f"slot{s}"], x, kind, cfg)
-            aux = aux + a
+            x, a, p = block(slot_params[f"slot{s}"], x, kind)
+            aux, pairs = aux + a, add(pairs, p)
         # sequence-parallel boundary: the scan carry (= remat residual)
         # lives sharded over (batch, seq) between blocks.
-        return (shard_activations(x), aux), None
+        return (shard_activations(x), aux, pairs), None
 
     aux = jnp.zeros((), jnp.float32)
+    held = cfg.moe is not None and cfg.moe.impl == "held"
+    pairs = jnp.zeros((), jnp.int32) if held else None  # None: no leaf in the carry
     # "layers": the layer stack's own work, such as the scan's slicing of
     # stacked weights and its stacking of residuals, outside any one block
     with jax.named_scope("layers"):
         if cfg.num_groups > 0:
-            body = _maybe_remat(group_body, cfg)
+            body = group_body if per_block else _maybe_remat(group_body, cfg)
             if cfg.scan_layers:
-                (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
+                (x, aux, pairs), _ = jax.lax.scan(body, (x, aux, pairs), params["groups"])
             else:  # unrolled: used by the dry-run cost probes
                 for i in range(cfg.num_groups):
                     slot_i = jax.tree.map(lambda a, i=i: a[i], params["groups"])
-                    (x, aux), _ = body((x, aux), slot_i)
+                    (x, aux, pairs), _ = body((x, aux, pairs), slot_i)
         for i, kind in enumerate(cfg.remainder):
-            x, a = _block_train(params["remainder"][i], x, kind, cfg)
-            aux = aux + a
+            x, a, p = _block_train(params["remainder"][i], x, kind, cfg)
+            aux, pairs = aux + a, add(pairs, p)
     logits = unembed(params, x, cfg)
-    return logits, aux
+    return logits, aux, pairs
+
+
+def forward(params: Params, batch, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Full forward pass -> (logits [B, S, V], moe_aux scalar)."""
+    return _forward(params, batch, cfg)[:2]
 
 
 def loss_fn(params: Params, batch, cfg: ModelConfig):
-    """Next-token cross-entropy with label masking and aux losses."""
-    logits, aux = forward(params, batch, cfg)
+    """Next-token cross-entropy with label masking and aux losses; with
+    held-expert layers, ``metrics["moe_pairs"]`` counts the (token, expert)
+    pairs they computed."""
+    logits, aux, pairs = _forward(params, batch, cfg)
     labels = batch["labels"]
     with jax.named_scope("loss"):
         # standard causal shift: logits[t] predicts labels[t]
@@ -335,6 +368,8 @@ def loss_fn(params: Params, batch, cfg: ModelConfig):
         if cfg.moe is not None:
             total = total + cfg.moe.router_aux_coef * aux
     metrics = {"ce": ce, "aux": aux, "tokens": denom}
+    if pairs is not None:
+        metrics["moe_pairs"] = pairs
     return total, metrics
 
 

@@ -84,9 +84,10 @@ def test_train_step_finite_grads(arch):
 def test_prefill_decode_roundtrip(arch):
     """Greedy decode from a prefilled cache matches teacher-forced logits."""
     cfg = get_smoke_config(arch)
-    if cfg.moe is not None:
+    if cfg.moe is not None and cfg.moe.impl != "held":
         # capacity drops make MoE decode diverge from batched forward by
-        # design; covered with high capacity in tests/test_models.py
+        # design; covered with high capacity in tests/test_models.py (the
+        # held-expert layer is dropless, so it runs here)
         pytest.skip("MoE capacity drops: covered separately")
     key = jax.random.PRNGKey(2)
     params = init_params(key, cfg)

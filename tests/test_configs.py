@@ -40,6 +40,7 @@ def test_exact_dims(arch):
         "musicgen-large": (48, 2048, 32, 32, 8192, 2048),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
         "distilgpt2-82m": (6, 768, 12, 12, 3072, 50257),
+        "mellum2-12b-a2.5b": (28, 2304, 32, 4, 896, 98304),
     }[arch]
     cfg = get_config(arch)
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.d_ff, cfg.vocab_size)

@@ -6,7 +6,8 @@ here lowers and compiles for ``v5e:2x2`` devices described by
 ``jax.experimental.topologies`` and checks what the chip's compiler made:
 the Pallas kernels at real widths (a ``tpu_custom_call`` in the HLO) —
 the WAN quantizers also at the widest trailing dimension of any config's
-parameter leaf, where a slab must still fit VMEM — the full-width distilgpt2-82m train step on one chip (fits its 16 GB), and a
+parameter leaf, where a slab must still fit VMEM — the full-width distilgpt2-82m train step on one chip (fits its 16 GB),
+Mellum2's one-chip stage step (GQA splash and the grouped-matmul kernel), and a
 2-pod ``hier_int8`` step on the four-chip host layout (2, 2, 1).  Each
 step program, and the serving prefill, must hold the Pallas flash-attention
 kernel under the ``attention`` scope (``models/attention.py``).
@@ -188,6 +189,24 @@ def test_attention_kernel_in_program(topo, one_chip, program):
         compiled = _compile(lambda p, b: prefill(p, b, cfg, max_len=576), params, batch)
     assert 0 < _peak_bytes(compiled) < V5E_HBM_BYTES
     assert _attention_kernels(compiled)
+
+
+def test_mellum2_stage_train_step_fits_one_chip(topo):
+    """mellum2-12b-a2.5b-l4e8 (4 layers of 2304, GQA 32/4, 8 of 64 experts
+    held, 12,288 vocabulary rows) trained at 2 x 8192 on one v5e chip: the
+    GQA attention of its sliding and full layers in the splash kernel, and
+    the held experts' matmuls in the grouped-matmul kernel."""
+    from repro.configs.mellum2_12b_a2_5b import stage_config
+
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    compiled = _lower_train_step(stage_config(), mesh, "hier", seq_len=8192, global_batch=2).compile()
+    assert 0 < _peak_bytes(compiled) < V5E_HBM_BYTES
+    assert len(_attention_kernels(compiled)) >= 3 * 4  # forward, remat's forward, backward; 4 layers
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call".*?, metadata=\{op_name="([^"]+)"', compiled.as_text(), re.S,
+    )
+    experts = [n for n in names if "moe_experts" in n.split("/")]
+    assert len(experts) >= 3 * 4 * 4  # 3 matmuls a layer: forward, remat's forward, 2 in the backward
 
 
 def test_two_pod_hier_int8_step_compiles(topo):
